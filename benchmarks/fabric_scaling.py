@@ -32,9 +32,12 @@ Four hard gates over the mesh-sharded checkerboard solver
    sequential chip-lns fabric-clock wall time at equal solution quality
    (best cut within 2%), both tiers at identical seeds/restarts/sweeps.
 
-Forced host devices (``XLA_FLAGS=--xla_force_host_platform_device_count``)
-must be set before jax imports, so the mesh phases run in ONE subprocess
-with that env; gates needing only 1 device run in-process. Writes
+On an accelerator host the mesh phases run in-process over the real
+devices (a chip belongs to one process, so no child may need it), with
+mesh sizes capped at the device count. On a CPU host they run in ONE
+subprocess over forced host devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count`` must be set before
+jax imports); gates needing only 1 device run in-process. Writes
 ``BENCH_fabric.json`` at the repo root (CI archives it).
 """
 from __future__ import annotations
@@ -85,10 +88,12 @@ def _fabric_clock(fab: dict) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# subprocess phase: everything that needs the forced 8-device host
+# mesh phase: everything that needs a multi-device mesh
 # ---------------------------------------------------------------------------
 
-def _phase_mesh(full: bool) -> dict:
+def _phase_mesh(full: bool, n_dev: int = FORCED_DEVICES) -> dict:
+    """Mesh gates over ``n_dev`` devices: forced host devices in the CPU
+    subprocess, the host's chips in-process on an accelerator."""
     from repro.core.hamiltonian import maxcut_value
     from repro.problems.gset import cut_from_energy, gset_problem
 
@@ -96,7 +101,7 @@ def _phase_mesh(full: bool) -> dict:
 
     # -- gate 1: weak scaling at fixed spins-per-die ----------------------
     sweeps = 3 if full else 2
-    for k in (1, 2, 4, 8):
+    for k in (k for k in (1, 2, 4, 8) if k <= n_dev):
         n = SPINS_PER_DIE * k
         p = gset_problem(n, seed=SEED, degree=6.0)
         s = _solver(mesh_devices=k, outer_sweeps=sweeps)
@@ -130,8 +135,9 @@ def _phase_mesh(full: bool) -> dict:
     # acceptance ORDER shifts the field ledger): acceptance must run in
     # canonical (problem, tile) order for this row to pass.
     out["mesh_invariance"] = []
-    for n_inv, k_pair in ((2 * SPINS_PER_DIE, (1, FORCED_DEVICES)),
-                          (3 * SPINS_PER_DIE, (1, 2))):
+    pairs = ((2 * SPINS_PER_DIE, (1, n_dev)),
+             (3 * SPINS_PER_DIE, (1, min(2, n_dev))))
+    for n_inv, k_pair in ((n, k) for n, k in pairs if k[1] > 1):
         p = gset_problem(n_inv, seed=SEED + 1, degree=6.0)
         reps = {k: _solver(mesh_devices=k, outer_sweeps=2).solve(
             p, runs=RESTARTS, seed=SEED) for k in k_pair}
@@ -155,7 +161,7 @@ def _phase_mesh(full: bool) -> dict:
     p = gset_problem(DUEL_N, seed=SEED + 2, degree=6.0)   # encode
     W = p.meta["W"]
 
-    s = _solver(mesh_devices=FORCED_DEVICES, outer_sweeps=duel_sweeps)
+    s = _solver(mesh_devices=n_dev, outer_sweeps=duel_sweeps)
     rep_f = s.solve(p, runs=RESTARTS, seed=SEED)          # solve
     fab = rep_f.meta["fabric"]
     if rep_f.dispatches != fab["n_colors"] * duel_sweeps:
@@ -199,7 +205,7 @@ def _phase_mesh(full: bool) -> dict:
             f"N={DUEL_N}")
     out["duel"] = {
         "n": DUEL_N, "outer_sweeps": duel_sweeps,
-        "mesh_devices": FORCED_DEVICES,
+        "mesh_devices": n_dev,
         "fabric": {"best_energy": e_fab, "best_cut": cut_sigma,
                    "dispatches": rep_f.dispatches, **fclock},
         "chip_lns": {"best_energy": e_chip,
@@ -262,9 +268,13 @@ def _phase_parity() -> dict:
 
 
 def run(full: bool = False):
+    import jax
     t0 = time.time()
     parity = _phase_parity()
-    mesh = _run_mesh_subprocess(full)
+    on_cpu = jax.default_backend() == "cpu"
+    n_dev = FORCED_DEVICES if on_cpu else len(jax.devices())
+    mesh = (_run_mesh_subprocess(full) if on_cpu
+            else _phase_mesh(full, n_dev=n_dev))
 
     clocks = [w["clock_per_sweep_s"] for w in mesh["weak"]]
     flatness = max(clocks) / min(clocks)
@@ -272,7 +282,7 @@ def run(full: bool = False):
         worst = max(mesh["weak"], key=lambda w: w["clock_per_sweep_s"])
         raise RuntimeError(
             f"weak scaling: fabric-clock per-sweep spread x{flatness:.2f} "
-            f"exceeds x{FLATNESS:.2f} across 1..{FORCED_DEVICES} dies "
+            f"exceeds x{FLATNESS:.2f} across 1..{n_dev} dies "
             f"(worst K={worst['mesh_devices']} at "
             f"{worst['clock_per_sweep_s'] * 1e3:.1f}ms/sweep)")
 
@@ -280,7 +290,8 @@ def run(full: bool = False):
         "spins_per_die": SPINS_PER_DIE, "restarts": RESTARTS,
         "inner_runs": INNER_RUNS, "anneal_sweeps": ANNEAL_SWEEPS,
         "die_us_per_anneal": DIE_US_PER_ANNEAL,
-        "forced_devices": FORCED_DEVICES,
+        "mesh_devices": n_dev,
+        "platform": jax.default_backend(),
         "weak_scaling": mesh["weak"],
         "weak_scaling_flatness": flatness,
         "flatness_gate": FLATNESS,

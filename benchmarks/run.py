@@ -14,6 +14,8 @@ import argparse
 import sys
 import traceback
 
+from repro.utils import enable_compile_cache
+
 from . import (device_robustness, fabric_scaling, fig4_success,
                fig4_trajectories, fig5_sr_density, fig5_tts,
                kernel_throughput, roofline_bench, serve_chaos, serve_fleet,
@@ -45,6 +47,7 @@ def main() -> None:
                     help="solver-matrix smoke only (CI job)")
     ap.add_argument("--only", nargs="*", choices=list(ALL))
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only or (["solver_matrix"] if args.quick else list(ALL))
     print("name,us_per_call,derived")
     failures = []

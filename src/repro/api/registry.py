@@ -306,10 +306,7 @@ class EngineSolver:
                       machine.device.noise_sigma > 0)
         plan = machine.engine.plan(big.num_problems, runs, big.n_pad,
                                    J=big.J, needs_scan=needs_scan)
-        rep.meta["engine_plan"] = {"path": plan.path,
-                                   "block_r": plan.block_r,
-                                   "j_dtype": plan.j_dtype,
-                                   "reason": plan.reason}
+        rep.meta["engine_plan"] = dataclasses.asdict(plan)
         return rep
 
 
@@ -778,6 +775,10 @@ class FabricSolver:
             dispatches += d
             meta["outer_sweeps"] = outer
             meta["fabric"] = lns.ledger
+            # the plan the die-aligned color-phase batches dispatched under
+            meta["engine_plan"] = dataclasses.asdict(lns.engine.plan(
+                max(lns.ledger["color_peaks"]) * lns.n_dies * runs,
+                self.inner_runs, delegate_n))
             meta["init_energies"] = {}
             for (e, s, e0), i in zip(results, big):
                 energies[i] = e

@@ -62,10 +62,7 @@ def _replicate_spin_axis(q8):
     constraint GSPMD all-gathers the post-scale f32 form (4x the bytes).
     The spin axis is forced replicated; problem/run axes stay unconstrained
     so run-sharded layouts remain communication-free."""
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:        # jax < 0.5 has no ambient-mesh API: no mesh
-        return q8               # context to constrain against, so no-op
-    mesh = get_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return q8
     U = jax.sharding.PartitionSpec.UNCONSTRAINED

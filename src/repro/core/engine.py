@@ -138,7 +138,7 @@ class AnnealEngine:
         ``needs_scan``: noise / trajectory recording — features only the
         scan path implements.
         """
-        on_tpu = jax.default_backend() == "tpu"
+        on_tpu = _on_tpu()
         j_dtype = self._auto_j_dtype(J)
         block_r = min(_next_pow2(R), 256)
         if needs_scan:
@@ -150,14 +150,15 @@ class AnnealEngine:
             cached = self._cache.get(self._key(P, R, N, j_dtype))
             if cached:
                 return EnginePlan(cached["path"], int(cached["block_r"]),
-                                  j_dtype, not on_tpu, reason="cache")
+                                  j_dtype, not on_tpu,
+                                  reason=_tuned_reason("cache", cached))
             path = "fused" if on_tpu else "scan"
             reason = "auto"
         elif path == "fused":
             cached = self._cache.get(self._key(P, R, N, j_dtype))
             if cached and cached["path"] == "fused":
                 block_r = int(cached["block_r"])
-                reason = "cache"
+                reason = _tuned_reason("cache", cached)
         return EnginePlan(path, block_r, j_dtype, not on_tpu, reason=reason)
 
     # -- autotuner ---------------------------------------------------------
@@ -186,7 +187,7 @@ class AnnealEngine:
                                         anneal_sweeps=probe_sweeps)
         if j_dtype is None:
             j_dtype = self._auto_j_dtype(np.asarray(J))
-        on_tpu = jax.default_backend() == "tpu"
+        on_tpu = _on_tpu()
 
         results: list[tuple[float, str, int]] = []
         if include_scan:
@@ -197,6 +198,10 @@ class AnnealEngine:
         # off-TPU it runs in interpret mode — a Python-speed correctness
         # harness whose timings must never be persisted as a winner (a tiny
         # workload could pin 'auto' dispatch to interpret mode via cache).
+        # A candidate that overflows the kernel's fast memory is skipped
+        # and recorded; any other failure is a fault and propagates — a
+        # fused kernel that never compiles must not quietly tune to scan.
+        skipped: list[int] = []
         if on_tpu:
             # Clamp oversized candidates to the padded run count instead of
             # skipping them, so small workloads still get >= 1 fused probe.
@@ -205,7 +210,10 @@ class AnnealEngine:
                     t = time_call(lambda br=br: kops.fused_anneal(
                         J, v0, probe_dev, self.perturbation, block_r=br,
                         j_dtype=j_dtype, interpret=False))
-                except Exception:                   # e.g. VMEM overflow
+                except Exception as e:
+                    if not _is_fast_memory_overflow(e):
+                        raise
+                    skipped.append(br)
                     continue
                 results.append((t, "fused", br))
         if not results:
@@ -217,12 +225,14 @@ class AnnealEngine:
         results.sort()
         best_t, best_path, best_br = results[0]
         key = self._key(P, R, N, j_dtype)
-        self._cache[key] = {"path": best_path, "block_r": best_br,
-                            "probe_s": best_t,
-                            "tuned_at": time.strftime("%Y-%m-%d %H:%M:%S")}
+        entry = {"path": best_path, "block_r": best_br, "probe_s": best_t,
+                 "tuned_at": time.strftime("%Y-%m-%d %H:%M:%S")}
+        if skipped:
+            entry["skipped_block_r"] = skipped
+        self._cache[key] = entry
         _store_cache(self.cache_path, self._cache)
         return EnginePlan(best_path, best_br, j_dtype, not on_tpu,
-                          reason="autotuned")
+                          reason=_tuned_reason("autotuned", entry))
 
     # -- execution ---------------------------------------------------------
     def run(self, J, v0, key: Optional[jax.Array] = None,
@@ -404,6 +414,28 @@ class BlockLNS:
         for p in range(len(Js)):
             out.append((energies(p), states[p].astype(np.int8), init_e[p]))
         return out, dispatches
+
+
+def _on_tpu() -> bool:
+    """Fused kernels compile here (the Pallas interpret rule, inverted)."""
+    from ..kernels.ising_anneal import default_interpret
+    return not default_interpret()
+
+
+def _is_fast_memory_overflow(e: Exception) -> bool:
+    """True for the compiler's VMEM-exhaustion refusal of a kernel — the
+    one failure that rules a block_r candidate out rather than the run."""
+    msg = str(e)
+    return "RESOURCE_EXHAUSTED" in msg and "vmem" in msg.lower()
+
+
+def _tuned_reason(base: str, entry: dict) -> str:
+    """Plan provenance, naming the fused candidates the tuner skipped."""
+    skipped = entry.get("skipped_block_r")
+    if not skipped:
+        return base
+    return (f"{base}; skipped fused block_r="
+            f"{','.join(str(b) for b in skipped)} (VMEM overflow)")
 
 
 def _is_pow2(x: float) -> bool:

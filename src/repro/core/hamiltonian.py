@@ -15,8 +15,15 @@ Conventions
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+#: Energies and cut weights are integer sums of DAC levels (up to the
+#: large boundary-field couplings of decomposition sub-instances) and must
+#: come out exact. A TPU's default f32 matmul rounds its operands to bf16,
+#: which loses integers above 256, so these contractions run at full f32.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def ising_energy(J, sigma):
@@ -35,7 +42,7 @@ def local_field(J, sigma):
     """f_i = sum_j J_ij s_j — the net coupling drive seen by node i.
     Broadcasts: sigma (..., R, N) against J (..., N, N)."""
     s = jnp.asarray(sigma, dtype=J.dtype)
-    return jnp.matmul(s, jnp.swapaxes(J, -1, -2))
+    return jnp.matmul(s, jnp.swapaxes(J, -1, -2), precision=EXACT)
 
 
 def flip_deltas(J, sigma):
@@ -111,5 +118,6 @@ def maxcut_value(W, sigma):
     W = jnp.asarray(W)
     s = jnp.asarray(sigma, dtype=W.dtype)
     total = jnp.sum(jnp.triu(W, k=1))
-    sWs = 0.5 * jnp.einsum("...i,ij,...j->...", s, W, s)  # sum_{i<j} W s s
+    sWs = 0.5 * jnp.einsum("...i,ij,...j->...", s, W, s,   # sum_{i<j} W s s
+                           precision=EXACT)
     return 0.5 * (total - sWs)

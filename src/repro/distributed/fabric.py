@@ -60,8 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import shard_map
-
 #: the fabric mesh axis name — one entry per virtual die.
 FABRIC_AXIS = "fabric"
 
@@ -69,20 +67,25 @@ FABRIC_AXIS = "fabric"
 def fabric_mesh(n_dies: Optional[int] = None) -> Mesh:
     """A 1-D mesh of ``n_dies`` local devices (default: all of them).
 
-    Under ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` the host
-    CPU presents K devices, so the fabric paths are exercised (and CI-
-    gated) without TPU hardware.
+    On an accelerator host the dies are its chips. On a CPU host,
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` presents K
+    devices, so the fabric paths are exercised (and CI-gated) without
+    accelerator hardware.
     """
     devs = jax.devices()
     k = len(devs) if n_dies is None else int(n_dies)
     if k < 1:
         raise ValueError(f"fabric mesh needs >= 1 die, got {k}")
     if k > len(devs):
+        platform = devs[0].platform
+        hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count={k} "
+                f"(before jax import) to emulate a {k}-die fabric on the "
+                f"host" if platform == "cpu" else
+                f"this host has {len(devs)} {devs[0].device_kind} "
+                f"device(s); ask for at most {len(devs)} dies")
         raise ValueError(
             f"fabric mesh of {k} dies requested but only {len(devs)} "
-            f"device(s) visible; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={k} (before jax "
-            f"import) to emulate a {k}-die fabric on the host")
+            f"{platform} device(s) visible; {hint}")
     return Mesh(np.asarray(devs[:k]), (FABRIC_AXIS,))
 
 
@@ -206,10 +209,10 @@ class FieldExchange:
             h = jnp.einsum("rc,nc->rn", s_loc, J_loc)
             return jax.lax.psum(h, FABRIC_AXIS)
 
-        return shard_map(partial_fields, mesh,
-                         in_specs=(P(None, FABRIC_AXIS),
-                                   P(None, FABRIC_AXIS)),
-                         out_specs=P(None, None))
+        return jax.shard_map(partial_fields, mesh=mesh,
+                             in_specs=(P(None, FABRIC_AXIS),
+                                       P(None, FABRIC_AXIS)),
+                             out_specs=P(None, None))
 
     def fields(self, s: np.ndarray) -> np.ndarray:
         """``h = s @ J`` for ±1 states ``s (R, N)`` -> ``(R, N)`` float32.
@@ -356,6 +359,7 @@ class FabricLNS:
 
         shard = NamedSharding(self.mesh, P(FABRIC_AXIS, None, None))
         dispatches = 0
+        batch_devices = 0
         sweeps_ledger = []
         for sweep in range(outer_sweeps):
             rec = {"t_fields": 0.0, "t_assemble": 0.0, "t_engine": 0.0,
@@ -396,6 +400,7 @@ class FabricLNS:
                 t0 = time.perf_counter()
                 batch_dev = jax.device_put(batch, shard)
                 v0_dev = jax.device_put(np.ascontiguousarray(v0b), shard)
+                batch_devices = len(batch_dev.sharding.device_set)
                 res = self.engine.run(batch_dev, v0_dev)
                 e = np.asarray(res.energy)             # (S, inner_runs)
                 sig = np.asarray(res.sigma)            # (S, inner, cb)
@@ -433,6 +438,8 @@ class FabricLNS:
 
         self.ledger = {
             "mesh_devices": self.n_dies,
+            # devices the dispatched batches actually spanned
+            "batch_devices": batch_devices,
             "n_colors": max(l.n_colors for l in layouts),
             "n_tiles": [l.n_tiles for l in layouts],
             # fabric-wide tiles-per-die peak of each color phase — the

@@ -17,20 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs.base import ModelConfig, ShapeConfig
 
 
-def shard_map(f, mesh, in_specs, out_specs, **kw):
-    """jax-version-compat shard_map: ``jax.shard_map`` on newer jax,
-    ``jax.experimental.shard_map.shard_map`` on 0.4.x (where the
-    ``check_vma`` kwarg was spelled ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
-
-
 def batch_axes(mesh: Mesh):
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

@@ -27,7 +27,9 @@ Grid: (P problems, R/BLOCK_R run blocks). Each program instance owns one
 (J_p, v-block) pair. MXU work per step: (BLOCK_R, N) @ (N, N).
 
 j_dtype variants (mirroring the scan path's §Perf iterations 2/3):
-  'float32'  — exact, works for every schedule.
+  'float32'  — works for every schedule; full f32 on CPU, while on a TPU
+               the MXU's default precision rounds the scaled spin vector
+               to bf16 (bitwise equal to 'bfloat16' there).
   'bfloat16' — halves the VMEM J tenant; integer DAC levels are exact in
                bf16, the bf16 cast of the scaled spin vector rounds the
                leak-decay factor (~3 decimal digits). Exact when the
@@ -54,6 +56,13 @@ from ..core.perturbation import (PerturbationConfig, scales_from_cols,
 
 DEFAULT_BLOCK_R = 128
 J_DTYPES = ("float32", "bfloat16", "int8")
+
+
+def default_interpret() -> bool:
+    """The one rule for Pallas interpret mode: compiled on TPU, interpreted
+    everywhere else (interpret mode is the CPU correctness harness, never a
+    fast path). Every kernel wrapper resolves ``interpret=None`` here."""
+    return jax.default_backend() != "tpu"
 
 
 def _anneal_kernel(j_ref, v_ref, out_ref, *, dev: DeviceModel,
@@ -103,14 +112,18 @@ def _anneal_kernel(j_ref, v_ref, out_ref, *, dev: DeviceModel,
                                     "interpret"))
 def fused_anneal_kernel(J, v0, *, dev: DeviceModel, pert: PerturbationConfig,
                         block_r: int = DEFAULT_BLOCK_R,
-                        j_dtype: str = "float32", interpret: bool = True):
+                        j_dtype: str = "float32",
+                        interpret: bool | None = None):
     """pallas_call wrapper. J (P,N,N), v0 (P,R,N); schedule derived in-kernel
     from (dev, pert) — there is NO schedule operand.
 
     Pads N to a lane multiple (128) and R to block_r; returns v_final (P,R,N)
-    unpadded. ``interpret=True`` runs the kernel body in Python on CPU — the
-    validation mode used in this repo; on TPU pass interpret=False.
+    unpadded. ``interpret=None`` resolves through :func:`default_interpret`
+    (compiled on TPU, interpreted elsewhere); ``interpret=True`` forces the
+    CPU validation mode.
     """
+    if interpret is None:
+        interpret = default_interpret()
     if j_dtype not in J_DTYPES:
         raise ValueError(f"j_dtype must be one of {J_DTYPES}, got {j_dtype!r}")
     if j_dtype == "int8" and not unit_scales(dev, pert):

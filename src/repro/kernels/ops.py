@@ -6,15 +6,18 @@ path/block-size selection and the autotune cache.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.binarize import sign_pm1
 from ..core.device_model import DeviceModel
 from ..core.hamiltonian import ising_energy
 from ..core.perturbation import PerturbationConfig
-from .ising_anneal import fused_anneal_kernel
+from .ising_anneal import DEFAULT_BLOCK_R, fused_anneal_kernel
 
 
 def fused_anneal(J, v0, dev: DeviceModel, pert: PerturbationConfig,
@@ -23,10 +26,9 @@ def fused_anneal(J, v0, dev: DeviceModel, pert: PerturbationConfig,
     """Full anneal via the fused VMEM kernel (schedule derived in-kernel).
 
     Returns (v_final, sigma, energy) matching ``core.annealer.anneal``'s
-    noise-free outputs. interpret defaults to True off-TPU.
+    noise-free outputs. ``interpret=None`` resolves through the kernel's
+    ``default_interpret`` (True off-TPU).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if j_dtype == "int8":
         # The jit'd kernel wrapper only sees traced values; guard the silent
         # astype(int8) truncation/wraparound here, where J is concrete.
@@ -39,13 +41,38 @@ def fused_anneal(J, v0, dev: DeviceModel, pert: PerturbationConfig,
             raise ValueError("j_dtype='int8' requires integer coupling "
                              "levels in [-127, 127] (run DeviceModel."
                              "quantize first)")
-    kw = {}
-    if block_r is not None:
-        kw["block_r"] = block_r
-    v = fused_anneal_kernel(jnp.asarray(J, jnp.float32),
-                            jnp.asarray(v0, jnp.float32),
-                            dev=dev, pert=pert, j_dtype=j_dtype,
-                            interpret=interpret, **kw)
+    if block_r is None:
+        block_r = DEFAULT_BLOCK_R
     Jf = jnp.asarray(J, jnp.float32)
+    kernel = functools.partial(fused_anneal_kernel, dev=dev, pert=pert,
+                               block_r=block_r, j_dtype=j_dtype,
+                               interpret=interpret)
+    split = _batch_split(Jf)
+    if split is not None:
+        kernel = _per_shard(*split, dev, pert, block_r, j_dtype, interpret)
+    v = kernel(Jf, jnp.asarray(v0, jnp.float32))
     sigma = sign_pm1(v, dev.threshold)
     return v, sigma, ising_energy(Jf, sigma)
+
+
+def _batch_split(J):
+    """``(mesh, axis)`` when J's problem axis is sharded over more than one
+    device — the fabric's die-aligned batches — else None."""
+    sh = getattr(J, "sharding", None)
+    if not isinstance(sh, NamedSharding) or sh.mesh.size == 1 \
+            or not sh.spec or sh.spec[0] is None:
+        return None
+    return sh.mesh, sh.spec[0]
+
+
+@functools.lru_cache(maxsize=16)
+def _per_shard(mesh, axis, dev, pert, block_r, j_dtype, interpret):
+    """The kernel run on each device's slice of the problem axis. XLA
+    cannot partition a Mosaic kernel itself, and problems never interact,
+    so each die anneals exactly the sub-instances it holds."""
+    kernel = functools.partial(fused_anneal_kernel, dev=dev, pert=pert,
+                               block_r=block_r, j_dtype=j_dtype,
+                               interpret=interpret)
+    spec = P(axis, None, None)
+    return jax.jit(jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec, check_vma=False))

@@ -35,7 +35,7 @@ term is a product with 0, and IEEE adds of 0 are exact), and the
 ``sign_pm1`` readout then maps them to +1 — the same pinned-pad convention
 as tabu-jax.
 
-``interpret=True`` (the default off-TPU) traces the identical jnp ops into
+``interpret=True`` (what the default resolves to off-TPU) traces the identical jnp ops into
 XLA, which is why ``sb_reference`` below — the same step expressions under
 a host-side ``lax.scan`` — matches the kernel bit-for-bit and serves as
 the parity oracle in tests/test_sb_jax.py.
@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.binarize import sign_pm1
+from .ising_anneal import default_interpret
 
 DEFAULT_BLOCK_R = 128
 SB_VARIANTS = ("aSB", "bSB", "dSB")
@@ -104,15 +105,18 @@ def _sb_kernel(j_ref, x_ref, y_ref, out_ref, *, variant: str, n_steps: int,
                                     "block_r", "interpret"))
 def fused_sb_kernel(Jc, x0, y0, *, variant: str = "bSB", n_steps: int = 400,
                     dt: float = 0.5, a0: float = 1.0,
-                    block_r: int = DEFAULT_BLOCK_R, interpret: bool = True):
+                    block_r: int = DEFAULT_BLOCK_R,
+                    interpret: bool | None = None):
     """pallas_call wrapper. Jc (P,N,N) c0-scaled couplings, x0/y0 (P,R,N).
 
     Returns x_final (P, R, N) float32 (continuous positions — callers
     binarize with ``sign_pm1``). Pads N to the 128-lane boundary and R to
     block_r with zeros; zero-state + zero-coupling pads are exactly inert,
-    so the trim is exact. ``interpret=True`` runs the body as traced jnp
-    ops on CPU; pass interpret=False on TPU.
+    so the trim is exact. ``interpret=None`` resolves through
+    ``default_interpret`` (compiled on TPU, traced jnp ops elsewhere).
     """
+    if interpret is None:
+        interpret = default_interpret()
     if variant not in SB_VARIANTS:
         raise ValueError(f"variant must be one of {SB_VARIANTS}, "
                          f"got {variant!r}")

@@ -26,6 +26,7 @@ import time
 from ..api import Problem
 from ..serve import (DEFAULT_QOS, FaultPlan, IsingFleet, IsingService,
                      QOS_CLASSES, ResiliencePolicy)
+from ..utils import enable_compile_cache
 
 
 def build_pool(sizes, density: float, pool: int, seed: int) -> list[Problem]:
@@ -162,6 +163,7 @@ def main():
                          "crashes the discrete paths re-solves on the "
                          "continuous integrator)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sizes = [int(s) for s in args.sizes.split(",")]
     pool = build_pool(sizes, args.density, args.pool, seed=args.seed)

@@ -45,6 +45,7 @@ from __future__ import annotations
 import argparse
 
 from ..api import ProblemSuite, get_solver, list_solvers, solve_suite
+from ..utils import enable_compile_cache
 
 #: --workload values that are plain Problem constructors, not zoo entries.
 _BUILTIN = ("random-qubo", "maxcut", "gset")
@@ -186,6 +187,7 @@ def main():
                     help="[ode-jax] lognormal spread of the gate-leak "
                          "time constant across chips")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.list_solvers:
         for name, caps in list_solvers().items():
@@ -208,7 +210,8 @@ def main():
     plan = report.meta.get("engine_plan")
     if plan:
         print(f"[engine] path={plan['path']} block_r={plan['block_r']} "
-              f"j_dtype={plan['j_dtype']} ({plan['reason']})")
+              f"j_dtype={plan['j_dtype']} interpret={plan['interpret']} "
+              f"({plan['reason']})")
     fab = report.meta.get("fabric")
     if fab:
         print(f"[fabric] {fab['mesh_devices']} dies, "

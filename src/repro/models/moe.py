@@ -152,7 +152,6 @@ def apply_moe(params, x, *, top_k: int, capacity_factor: float = 1.25,
                 P(None, None, "model"),                # wg
                 P(None, "model", None))                # wo: F sliced
     out_specs = P(bax if bax else None, None, None)
-    from ..distributed.sharding import shard_map as compat_shard_map
-    fn = compat_shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(x, params["router"], params["wi"], params["wg"], params["wo"])

@@ -25,13 +25,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.hamiltonian import EXACT
+
 
 def random_init_state(J, key):
     """Uniform ±1 spins plus consistent local fields / energy. J (n,n)."""
     n = J.shape[-1]
     s = jnp.where(jax.random.bernoulli(key, 0.5, (n,)), 1.0, -1.0)
     f = J @ s                                    # (n,) local fields
-    e = -0.5 * jnp.dot(s, f)
+    e = -0.5 * jnp.dot(s, f, precision=EXACT)    # |f| can exceed 256
     return s, f, e
 
 

@@ -79,14 +79,15 @@ def simulated_bifurcation_jax_runs(J, n_true=None, variant: str = "bSB",
                                    n_steps: int = 400, n_restarts: int = 16,
                                    dt: float = 0.5, a0: float = 1.0,
                                    seed: int = 0, block_r=None,
-                                   interpret: bool = True):
+                                   interpret=None):
     """Per-restart SB results for a (padded) problem batch, one dispatch.
 
     J: (P, n, n) or (n, n) level-space couplings (rows/cols >= each
     problem's true size must be zero — suite-bucket padding). ``n_true``:
     (P,) true spin counts (default: full n). Returns ``(energies (P, R)
     float64, sigma (P, R, n) int8)`` — energies scored on the host in
-    float64 against the ORIGINAL J; padded spins read +1.
+    float64 against the ORIGINAL J; padded spins read +1. ``interpret``
+    defaults to the kernel's backend rule (compiled on TPU).
     """
     if variant not in SB_VARIANTS:
         raise ValueError(f"variant must be one of {SB_VARIANTS}, "

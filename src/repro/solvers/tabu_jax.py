@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.hamiltonian import EXACT
+
 #: aspiration / improvement tolerance. Level-space energies are exact
 #: integers (integer J, ±1 spins), comfortably inside float32's 2^24
 #: integer range — anything below 0.5 distinguishes them.
@@ -51,7 +53,7 @@ def _tabu_single(J, key, n_true, n_iters, tenure, max_iters: int,
     s = jnp.where(jax.random.bernoulli(k_init, 0.5, (n,)), 1.0, -1.0)
     s = jnp.where(valid, s, 1.0)                 # padded spins pinned (inert)
     f = J @ s
-    e = -0.5 * jnp.dot(s, f)
+    e = -0.5 * jnp.dot(s, f, precision=EXACT)    # |f| can exceed 256
 
     def step(carry, it):
         s, f, e, best_e, best_s, tabu_until, done, used, since = carry

@@ -42,10 +42,30 @@ import json
 import os
 from typing import Callable, Iterable, Optional
 
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed directory in the checkout (git-ignored) — the path is
+#: part of the cache key, so it must not move between runs.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_compile_cache")
+
 try:
     import fcntl
 except ImportError:                      # non-POSIX: fall back to lockless
     fcntl = None                         # (atomic rename still holds)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point (call
+    it from ``main``, never at import). Where ``JAX_COMPILATION_CACHE_DIR``
+    is set, JAX reads it itself and nothing is set here; otherwise the
+    cache goes to :data:`COMPILE_CACHE_DIR`. Returns the directory used."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 @contextlib.contextmanager

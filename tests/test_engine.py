@@ -154,6 +154,42 @@ def test_engine_autotune_cache_roundtrip(tmp_path):
     assert plan2.path == plan.path and plan2.block_r == plan.block_r
 
 
+def _fake_tpu_autotune(monkeypatch, fused):
+    """Drive the autotuner's fused branch off-TPU with a stand-in kernel."""
+    from repro.core import engine as engine_mod
+    from repro.kernels import ops
+    monkeypatch.setattr(engine_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "fused_anneal", fused)
+
+
+def test_autotune_skips_vmem_overflow_and_names_it(tmp_path, monkeypatch):
+    def fused(*a, block_r, **kw):
+        if block_r == 32:
+            raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
+                               "memory space vmem while allocating")
+        return jnp.zeros(())
+    _fake_tpu_autotune(monkeypatch, fused)
+    cache = str(tmp_path / "autotune.json")
+    dev = DeviceModel(n_spins=16, anneal_sweeps=0.25)
+    plan = AnnealEngine(device=dev, cache_path=cache).autotune(
+        1, 32, 16, probe_sweeps=0.125, candidates=(16, 32))
+    assert plan.reason == "autotuned; skipped fused block_r=32 (VMEM overflow)"
+    # the skip survives in the cache and in every plan read from it
+    plan2 = AnnealEngine(device=dev, cache_path=cache).plan(1, 32, 16)
+    assert plan2.reason == "cache; skipped fused block_r=32 (VMEM overflow)"
+
+
+def test_autotune_reraises_fused_faults(tmp_path, monkeypatch):
+    def fused(*a, **kw):
+        raise ValueError("Mosaic failed to compile TPU kernel")
+    _fake_tpu_autotune(monkeypatch, fused)
+    eng = AnnealEngine(device=DeviceModel(n_spins=16, anneal_sweeps=0.25),
+                       cache_path=str(tmp_path / "autotune.json"))
+    with pytest.raises(ValueError, match="Mosaic"):
+        eng.autotune(1, 32, 16, probe_sweeps=0.125, candidates=(16, 32))
+    assert not (tmp_path / "autotune.json").exists()   # nothing tuned
+
+
 def test_machine_backends_agree_via_engine():
     ps = problem_set(48, 0.5, 1, seed=5)
     a = IsingMachine(backend="jnp").solve(ps.J, num_runs=32, seed=3)

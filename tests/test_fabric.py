@@ -15,12 +15,12 @@ from repro.problems.gset import (cut_from_energy, dump_gset, gset_problem,
 SEED = 42
 
 
-def _engine():
+def _engine(path="scan"):
     import dataclasses as dc
 
     from repro.core.device_model import DeviceModel
     dev = dc.replace(DeviceModel(), anneal_sweeps=0.5)
-    return AnnealEngine(device=dev, path="scan")
+    return AnnealEngine(device=dev, path=path)
 
 
 # ---------------------------------------------------------------------------
@@ -74,8 +74,8 @@ def test_layout_occupancy_counts_idle_and_padding():
 def test_layout_rejects_bad_args():
     with pytest.raises(ValueError):
         FabricLayout.build(100, n_dies=0)
-    with pytest.raises(ValueError):
-        fabric_mesh(len(jax.devices()) + 1)
+    with pytest.raises(ValueError, match="device_count"):
+        fabric_mesh(len(jax.devices()) + 1)   # CPU host: emulation hint
 
 
 # ---------------------------------------------------------------------------
@@ -120,11 +120,12 @@ def test_field_exchange_rejects_bad_shapes():
 # FabricLNS
 # ---------------------------------------------------------------------------
 
-def _solve_fabric(n=150, restarts=3, sweeps=2, seed=SEED, **kw):
+def _solve_fabric(n=150, restarts=3, sweeps=2, seed=SEED, path="scan",
+                  **kw):
     rng = np.random.default_rng(seed)
     J = rng.integers(-15, 16, size=(n, n)).astype(np.float64)
     J = np.triu(J, 1) + np.triu(J, 1).T
-    lns = FabricLNS(_engine(), inner_runs=4, **kw)
+    lns = FabricLNS(_engine(path), inner_runs=4, **kw)
     out, d = lns.solve([J], restarts=restarts, outer_sweeps=sweeps,
                        seed=seed)
     return J, lns, out, d
@@ -196,10 +197,13 @@ def test_fabric_multi_problem_batch():
     # runs in canonical (problem, tile) order
     (378, 2),
 ])
-def test_fabric_bitwise_mesh_invariant(n, k):
+# 'fused' runs the Pallas kernel once per die on its slice of the batch
+@pytest.mark.parametrize("path", ["scan", "fused"])
+def test_fabric_bitwise_mesh_invariant(n, k, path):
     k = len(jax.devices()) if k is None else k
-    _, _, out_1, _ = _solve_fabric(n=n, mesh=fabric_mesh(1))
-    _, _, out_k, _ = _solve_fabric(n=n, mesh=fabric_mesh(k))
+    _, _, out_1, _ = _solve_fabric(n=n, mesh=fabric_mesh(1), path=path)
+    _, lns, out_k, _ = _solve_fabric(n=n, mesh=fabric_mesh(k), path=path)
+    assert lns.ledger["batch_devices"] == k
     assert np.array_equal(out_1[0][0], out_k[0][0])
     assert np.array_equal(out_1[0][1], out_k[0][1])
 

@@ -27,17 +27,27 @@ def _random_ising(n, seed, P=1):
 
 @pytest.mark.parametrize("variant", SB_VARIANTS)
 def test_sb_matches_brute_force_small(variant):
+    """bSB and dSB reach the ground state of every instance. aSB does not
+    promise that: without inelastic walls every restart follows the same
+    adiabatic bifurcation branch, so on problem 1 (ground state -88) almost
+    all restarts settle at -82 whatever the init draw (1 of 128 restarts
+    over 8 seeds reached -88; dt 0.1-0.5 and 400-1000 steps alike). What
+    aSB does deliver, across init draws: the ground state on most
+    instances and within 10% of it on all."""
     J = _random_ising(12, seed=7, P=3)
-    # aSB has no inelastic walls, so its amplitude error compounds with dt;
-    # the smaller step keeps the analog variant on the ground states too.
+    # aSB has no inelastic walls, so its amplitude error compounds with dt
     dt = 0.25 if variant == "aSB" else 0.5
     e, s = simulated_bifurcation_jax_runs(J, variant=variant, n_steps=400,
                                           n_restarts=16, dt=dt, seed=0)
     assert e.shape == (3, 16) and s.shape == (3, 16, 12)
     assert s.dtype == np.int8 and set(np.unique(s)) <= {-1, 1}
+    ground = np.array([brute_force_ground_state(J[p])[0] for p in range(3)])
+    if variant == "aSB":
+        assert np.isclose(e.min(axis=1), ground).sum() >= 2
+        assert np.all(e.min(axis=1) <= 0.9 * ground)    # energies < 0
+    else:
+        np.testing.assert_allclose(e.min(axis=1), ground)
     for p in range(3):
-        e_bf, _ = brute_force_ground_state(J[p])
-        assert np.isclose(e[p].min(), e_bf), (variant, p)
         # reported energies are exactly the energies of the reported spins
         best = int(np.argmin(e[p]))
         sb = s[p, best].astype(np.float64)

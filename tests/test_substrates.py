@@ -84,7 +84,6 @@ def test_int8_compression_roundtrip(rng):
 
 def test_compressed_psum_error_feedback(rng):
     from repro.optim import compressed_psum
-    from repro.distributed.sharding import shard_map
     from repro.launch.mesh import _mesh_kwargs
     mesh = jax.make_mesh((1,), ("d",), **_mesh_kwargs(1))
     x = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
@@ -93,7 +92,7 @@ def test_compressed_psum_error_feedback(rng):
         out, resid = compressed_psum(x, "d")
         return out, resid
 
-    out, resid = jax.jit(shard_map(
+    out, resid = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(None),
         out_specs=jax.sharding.PartitionSpec(None)))(x)
     np.testing.assert_allclose(np.asarray(out + resid), np.asarray(x),
