@@ -29,6 +29,7 @@ from typing import Optional, Protocol, runtime_checkable
 import numpy as np
 
 from ..solvers.brute_force import BRUTE_FORCE_MAX_N
+from ..tracing import span
 from .batching import CHIP_BLOCK, padded_size, plan_buckets
 from .budget import budget_factor, search_effort
 from .oracle import best_known_energies, reconcile_best_known
@@ -205,20 +206,22 @@ def _bucketed_report(suite, solver_name, runs, block, run_bucket,
     wall = compile_s = 0.0
     for b_idx, bucket in enumerate(buckets):
         if warmup:
-            t0 = time.time()
+            t0 = time.perf_counter()
             for arr in run_bucket(bucket, b_idx):
                 np.asarray(arr)                    # force device sync
-            t_first = time.time() - t0
-        t0 = time.time()
+            t_first = time.perf_counter() - t0
+        t0 = time.perf_counter()
         e, s = run_bucket(bucket, b_idx)
-        e = np.asarray(e, dtype=np.float64)
-        s = np.asarray(s)
-        dt = time.time() - t0
+        with span("registry.scatter"):
+            e = np.asarray(e, dtype=np.float64)
+            s = np.asarray(s)
+        dt = time.perf_counter() - t0
         wall += dt
         if warmup:
             compile_s += max(0.0, t_first - dt)
         outputs.append((e, s))
-    energies, sigmas = plan.scatter(outputs)
+    with span("registry.scatter"):
+        energies, sigmas = plan.scatter(outputs)
     return SolveReport(
         solver=solver_name, runs=runs, energies=energies, best_sigma=sigmas,
         problem_hashes=suite.hashes, sizes=suite.sizes,
@@ -262,8 +265,9 @@ class EngineSolver:
         from ..core.device_model import DeviceModel
         from ..core.machine import IsingMachine
         if self._machine is not None:
-            m = self._machine
-        else:
+            return self._machine
+        # a fresh engine reads the autotune cache file: inside the span
+        with span("registry.make_machine"):
             dev = DeviceModel()
             if budget is not None:
                 dev = dc.replace(dev, anneal_sweeps=dev.anneal_sweeps *
@@ -283,31 +287,32 @@ class EngineSolver:
 
         suite = as_suite(suite)
         _check_max_n(suite, self.caps, self.name, block)
-        machine = self._make_machine(budget)
+        with span("registry.solve", problems=len(suite), runs=runs):
+            machine = self._make_machine(budget)
 
-        def run_bucket(bucket, b_idx):
-            key = (jax.random.PRNGKey(seed + 10007 * b_idx)
-                   if self.variant == "noise" else None)
-            out = machine.solve(bucket.J, num_runs=runs,
-                                seed=seed + 7919 * b_idx, key=key,
-                                quantize=False)
-            return out.energy, out.sigma
+            def run_bucket(bucket, b_idx):
+                key = (jax.random.PRNGKey(seed + 10007 * b_idx)
+                       if self.variant == "noise" else None)
+                out = machine.solve(bucket.J, num_runs=runs,
+                                    seed=seed + 7919 * b_idx, key=key,
+                                    quantize=False)
+                return out.energy, out.sigma
 
-        buckets = suite.buckets(block)
-        rep = _bucketed_report(suite, self.name, runs, block, run_bucket,
-                               meta={"variant": self.variant,
-                                     "backend": self.backend},
-                               buckets=buckets, warmup=self.warmup)
-        # Report the plan the biggest bucket ACTUALLY resolved to: with the
-        # real J (int8 auto-select needs concrete levels) and the noise
-        # variant's forced-scan feature flag.
-        big = max(buckets, key=lambda b: b.n_pad)
-        needs_scan = (self.variant == "noise" and
-                      machine.device.noise_sigma > 0)
-        plan = machine.engine.plan(big.num_problems, runs, big.n_pad,
-                                   J=big.J, needs_scan=needs_scan)
-        rep.meta["engine_plan"] = dataclasses.asdict(plan)
-        return rep
+            buckets = suite.buckets(block)
+            rep = _bucketed_report(suite, self.name, runs, block, run_bucket,
+                                   meta={"variant": self.variant,
+                                         "backend": self.backend},
+                                   buckets=buckets, warmup=self.warmup)
+            # Report the plan the biggest bucket ACTUALLY resolved to: with the
+            # real J (int8 auto-select needs concrete levels) and the noise
+            # variant's forced-scan feature flag.
+            big = max(buckets, key=lambda b: b.n_pad)
+            needs_scan = (self.variant == "noise" and
+                          machine.device.noise_sigma > 0)
+            plan = machine.engine.plan(big.num_problems, runs, big.n_pad,
+                                       J=big.J, needs_scan=needs_scan)
+            rep.meta["engine_plan"] = dataclasses.asdict(plan)
+            return rep
 
 
 @register_solver("sa-jax", needs_oracle=True, exact=False, device="jax")
@@ -725,73 +730,74 @@ class FabricSolver:
         from ..core.engine import lns_blocks
         from ..distributed.fabric import FabricLNS, fabric_mesh
         suite = as_suite(suite)
-        wall = 0.0
-        delegate_n = min(block, EngineSolver.caps.max_n or block)
-        small = [i for i, n in enumerate(suite.sizes) if n <= delegate_n]
-        big = [i for i, n in enumerate(suite.sizes) if n > delegate_n]
+        with span("registry.solve", problems=len(suite), runs=runs):
+            wall = 0.0
+            delegate_n = min(block, EngineSolver.caps.max_n or block)
+            small = [i for i, n in enumerate(suite.sizes) if n <= delegate_n]
+            big = [i for i, n in enumerate(suite.sizes) if n > delegate_n]
 
-        energies = [None] * len(suite)
-        sigmas = [None] * len(suite)
-        dispatches = 0
-        compile_s = 0.0
-        meta = {"block": block, "inner_runs": self.inner_runs,
-                "lns_problems": big}
+            energies = [None] * len(suite)
+            sigmas = [None] * len(suite)
+            dispatches = 0
+            compile_s = 0.0
+            meta = {"block": block, "inner_runs": self.inner_runs,
+                    "lns_problems": big}
 
-        if small:
-            sub = ProblemSuite([suite[i] for i in small])
-            rep = EngineSolver(backend=self.backend,
-                               warmup=self.warmup).solve(
-                sub, runs=runs, seed=seed, budget=None, block=delegate_n)
-            for k, i in enumerate(small):
-                energies[i] = rep.energies[k]
-                sigmas[i] = rep.best_sigma[k]
-            dispatches += rep.dispatches
-            compile_s += rep.compile_s
-            wall += rep.wall_s
-            meta["engine_plan"] = rep.meta.get("engine_plan")
+            if small:
+                sub = ProblemSuite([suite[i] for i in small])
+                rep = EngineSolver(backend=self.backend,
+                                   warmup=self.warmup).solve(
+                    sub, runs=runs, seed=seed, budget=None, block=delegate_n)
+                for k, i in enumerate(small):
+                    energies[i] = rep.energies[k]
+                    sigmas[i] = rep.best_sigma[k]
+                dispatches += rep.dispatches
+                compile_s += rep.compile_s
+                wall += rep.wall_s
+                meta["engine_plan"] = rep.meta.get("engine_plan")
 
-        if big:
-            n_blocks = max(len(lns_blocks(suite[i].n, delegate_n - 1))
-                           for i in big)
-            # same effort mapping as chip-lns so the two tiers compare at
-            # equal work: outer sweeps, restarts, inner runs all line up
-            outer = self.outer_sweeps or max(4, 2 * n_blocks)
-            outer = search_effort(outer, runs, budget).iters
-            mesh = fabric_mesh(self.mesh_devices)
-            lns = FabricLNS(self._engine(), mesh=mesh,
-                            chip_block=delegate_n,
-                            inner_runs=self.inner_runs)
-            big_J = [suite[i].J_levels.astype(np.float64) for i in big]
-            if self.warmup:
-                tw = time.time()
-                lns.solve(big_J, restarts=runs, outer_sweeps=outer,
-                          seed=seed + 104729)
-                t_first = time.time() - tw
-            t0 = time.time()
-            results, d = lns.solve(big_J, restarts=runs,
-                                   outer_sweeps=outer, seed=seed + 104729)
-            if self.warmup:
-                compile_s += max(0.0, t_first - (time.time() - t0))
-            dispatches += d
-            meta["outer_sweeps"] = outer
-            meta["fabric"] = lns.ledger
-            # the plan the die-aligned color-phase batches dispatched under
-            meta["engine_plan"] = dataclasses.asdict(lns.engine.plan(
-                max(lns.ledger["color_peaks"]) * lns.n_dies * runs,
-                self.inner_runs, delegate_n))
-            meta["init_energies"] = {}
-            for (e, s, e0), i in zip(results, big):
-                energies[i] = e
-                sigmas[i] = s[int(np.argmin(e))]
-                meta["init_energies"][i] = e0.tolist()
-            wall += time.time() - t0
+            if big:
+                n_blocks = max(len(lns_blocks(suite[i].n, delegate_n - 1))
+                               for i in big)
+                # same effort mapping as chip-lns so the two tiers compare at
+                # equal work: outer sweeps, restarts, inner runs all line up
+                outer = self.outer_sweeps or max(4, 2 * n_blocks)
+                outer = search_effort(outer, runs, budget).iters
+                mesh = fabric_mesh(self.mesh_devices)
+                lns = FabricLNS(self._engine(), mesh=mesh,
+                                chip_block=delegate_n,
+                                inner_runs=self.inner_runs)
+                big_J = [suite[i].J_levels.astype(np.float64) for i in big]
+                if self.warmup:
+                    tw = time.time()
+                    lns.solve(big_J, restarts=runs, outer_sweeps=outer,
+                              seed=seed + 104729)
+                    t_first = time.time() - tw
+                t0 = time.time()
+                results, d = lns.solve(big_J, restarts=runs,
+                                       outer_sweeps=outer, seed=seed + 104729)
+                if self.warmup:
+                    compile_s += max(0.0, t_first - (time.time() - t0))
+                dispatches += d
+                meta["outer_sweeps"] = outer
+                meta["fabric"] = lns.ledger
+                # the plan the die-aligned color-phase batches dispatched under
+                meta["engine_plan"] = dataclasses.asdict(lns.engine.plan(
+                    max(lns.ledger["color_peaks"]) * lns.n_dies * runs,
+                    self.inner_runs, delegate_n))
+                meta["init_energies"] = {}
+                for (e, s, e0), i in zip(results, big):
+                    energies[i] = e
+                    sigmas[i] = s[int(np.argmin(e))]
+                    meta["init_energies"][i] = e0.tolist()
+                wall += time.time() - t0
 
-        return SolveReport(
-            solver=self.name, runs=runs, energies=energies,
-            best_sigma=sigmas, problem_hashes=suite.hashes,
-            sizes=suite.sizes, scales=tuple(p.scale for p in suite),
-            wall_s=wall, compile_s=compile_s, dispatches=dispatches,
-            meta=meta)
+            return SolveReport(
+                solver=self.name, runs=runs, energies=energies,
+                best_sigma=sigmas, problem_hashes=suite.hashes,
+                sizes=suite.sizes, scales=tuple(p.scale for p in suite),
+                wall_s=wall, compile_s=compile_s, dispatches=dispatches,
+                meta=meta)
 
 
 @register_solver("ode-jax", needs_oracle=True, exact=False, device="jax",

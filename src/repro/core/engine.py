@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tracing import span
 from ..utils import load_json_cache, store_json_cache
 from .annealer import anneal, AnnealResult
 from .device_model import DeviceModel
@@ -238,36 +239,39 @@ class AnnealEngine:
     def run(self, J, v0, key: Optional[jax.Array] = None,
             record_every: int = 0) -> AnnealResult:
         """Anneal quantized couplings J (P,N,N) from voltages v0 (P,R,N)."""
-        J = jnp.asarray(J, jnp.float32)
-        v0 = jnp.asarray(v0, jnp.float32)
-        P, N, _ = J.shape
-        R = v0.shape[1]
-        dev = self.device
-        if N != dev.n_spins:
-            dev = dataclasses.replace(dev, n_spins=N)
-        needs_scan = bool(record_every) or (
-            key is not None and dev.noise_sigma > 0)
-        run_j_dtype = self._auto_j_dtype(J)
-        # No point tuning when the path is pinned to 'scan': plan() never
-        # consults the cache on that branch, so the search would be wasted.
-        if self.autotune_enabled and not needs_scan and \
-                self.path != "scan" and \
-                self._key(P, R, N, run_j_dtype) not in self._cache:
-            # Tune under the REAL workload's j_dtype so the cache entry
-            # matches this lookup (the probe J is always integer levels).
-            self.autotune(P, R, N, j_dtype=run_j_dtype)
-        plan = self.plan(P, R, N, J=J, needs_scan=needs_scan)
+        with span("engine.run") as sp:
+            J = jnp.asarray(J, jnp.float32)
+            v0 = jnp.asarray(v0, jnp.float32)
+            P, N, _ = J.shape
+            R = v0.shape[1]
+            dev = self.device
+            if N != dev.n_spins:
+                dev = dataclasses.replace(dev, n_spins=N)
+            needs_scan = bool(record_every) or (
+                key is not None and dev.noise_sigma > 0)
+            run_j_dtype = self._auto_j_dtype(J)
+            # No point tuning when the path is pinned to 'scan': plan() never
+            # consults the cache on that branch, so the search would be wasted.
+            if self.autotune_enabled and not needs_scan and \
+                    self.path != "scan" and \
+                    self._key(P, R, N, run_j_dtype) not in self._cache:
+                # Tune under the REAL workload's j_dtype so the cache entry
+                # matches this lookup (the probe J is always integer levels).
+                self.autotune(P, R, N, j_dtype=run_j_dtype)
+            plan = self.plan(P, R, N, J=J, needs_scan=needs_scan)
+            sp.set_metadata(path=plan.path, block_r=plan.block_r,
+                            j_dtype=plan.j_dtype)
 
-        if plan.path == "scan":
-            return anneal(J, v0, dev, self.perturbation, key=key,
-                          record_every=record_every)
+            if plan.path == "scan":
+                return anneal(J, v0, dev, self.perturbation, key=key,
+                              record_every=record_every)
 
-        from ..kernels import ops as kops
-        v, sigma, energy = kops.fused_anneal(
-            J, v0, dev, self.perturbation, interpret=plan.interpret,
-            block_r=plan.block_r, j_dtype=plan.j_dtype)
-        return AnnealResult(v_final=v, sigma=sigma, energy=energy,
-                            energy_traj=None)
+            from ..kernels import ops as kops
+            v, sigma, energy = kops.fused_anneal(
+                J, v0, dev, self.perturbation, interpret=plan.interpret,
+                block_r=plan.block_r, j_dtype=plan.j_dtype)
+            return AnnealResult(v_final=v, sigma=sigma, energy=energy,
+                                energy_traj=None)
 
 
 # ---------------------------------------------------------------------------

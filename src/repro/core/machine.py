@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tracing import span
 from .device_model import DeviceModel
 from .engine import AnnealEngine
 from .lfsr import lfsr_voltage_inits
@@ -90,22 +91,32 @@ class IsingMachine:
             dev = dataclasses.replace(dev, n_spins=N)
 
         Jq = dev.quantize(J) if quantize else jnp.asarray(J)
-        v0 = np.stack([
-            lfsr_voltage_inits(N, num_runs, seed=seed + 7919 * p,
-                               vdd=dev.vdd, swing=dev.init_swing)
-            for p in range(P)
-        ])  # (P, R, N)
+        with span("machine.lfsr_init", problems=P, runs=num_runs):
+            v0 = np.stack([
+                lfsr_voltage_inits(N, num_runs, seed=seed + 7919 * p,
+                                   vdd=dev.vdd, swing=dev.init_swing)
+                for p in range(P)
+            ])  # (P, R, N)
 
         # All paths dispatch through the AnnealEngine; it falls back to the
         # scan path automatically when noise/trajectory recording is asked
         # for (features the fused kernel doesn't materialize).
         res = self.engine.run(Jq, v0, key=key, record_every=record_every)
 
-        return SolveOutput(
-            sigma=np.asarray(res.sigma), energy=np.asarray(res.energy),
-            v_final=np.asarray(res.v_final),
-            energy_traj=(None if res.energy_traj is None
-                         else np.asarray(res.energy_traj)))
+        # the copies below would wait anyway; waiting first keeps the
+        # device's time out of the readback span
+        with span("machine.wait"):
+            jax.block_until_ready((res.v_final, res.sigma, res.energy))
+        with span("machine.readback") as sp:
+            out = SolveOutput(
+                sigma=np.asarray(res.sigma), energy=np.asarray(res.energy),
+                v_final=np.asarray(res.v_final),
+                energy_traj=(None if res.energy_traj is None
+                             else np.asarray(res.energy_traj)))
+            sp.set_metadata(bytes=sum(
+                a.nbytes for a in (out.sigma, out.energy, out.v_final,
+                                   out.energy_traj) if a is not None))
+        return out
 
     # ------------------------------------------------------------------
     def gradient_descent_baseline(self) -> "IsingMachine":

@@ -60,6 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..tracing import phase
+
 #: the fabric mesh axis name — one entry per virtual die.
 FABRIC_AXIS = "fabric"
 
@@ -369,70 +371,72 @@ class FabricLNS:
                 if cplan is None:
                     continue
                 batch, spans, accept = tmpl
+                # the ledger's phase times and the trace's spans share one
+                # boundary per phase
+                where = dict(sweep=sweep, color=c, tiles=len(accept))
 
                 # 1) halo exchange: sharded J_tile @ s row-sums (exact)
-                t0 = time.perf_counter()
-                h_all = [exchangers[p].fields(states[p])
-                         if any(s is not None and s[0] == p
-                                for s, _ in spans) else None
-                         for p in range(len(Js))]
-                rec["t_fields"] += time.perf_counter() - t0
+                with phase("fabric.fields", rec, "t_fields", **where):
+                    h_all = [exchangers[p].fields(states[p])
+                             if any(s is not None and s[0] == p
+                                    for s, _ in spans) else None
+                             for p in range(len(Js))]
 
                 # 2) stamp the ancilla boundary row/col into the template
-                t0 = time.perf_counter()
-                for slot, rows in spans:
-                    if slot is None:
-                        continue
-                    p, t = slot
-                    lo, hi, Jbb64, _, _ = tiles[slot]
-                    m = hi - lo
-                    Sb = states[p][:, lo:hi]
-                    h = h_all[p][:, lo:hi].astype(np.float64) - Sb @ Jbb64
-                    batch[rows, 0, 1:m + 1] = h
-                    batch[rows, 1:m + 1, 0] = h
-                v0 = lfsr_voltage_inits(
-                    cb, self.inner_runs,
-                    seed=seed + 7919 * (sweep + 1) + 104729 * (c + 1))
-                v0b = np.broadcast_to(v0, (batch.shape[0],) + v0.shape)
-                rec["t_assemble"] += time.perf_counter() - t0
+                with phase("fabric.assemble", rec, "t_assemble", **where):
+                    for slot, rows in spans:
+                        if slot is None:
+                            continue
+                        p, t = slot
+                        lo, hi, Jbb64, _, _ = tiles[slot]
+                        m = hi - lo
+                        Sb = states[p][:, lo:hi]
+                        h = (h_all[p][:, lo:hi].astype(np.float64)
+                             - Sb @ Jbb64)
+                        batch[rows, 0, 1:m + 1] = h
+                        batch[rows, 1:m + 1, 0] = h
+                    v0 = lfsr_voltage_inits(
+                        cb, self.inner_runs,
+                        seed=seed + 7919 * (sweep + 1) + 104729 * (c + 1))
+                    v0b = np.broadcast_to(v0, (batch.shape[0],) + v0.shape)
 
                 # 3) ONE die-aligned engine dispatch for the color class
-                t0 = time.perf_counter()
-                batch_dev = jax.device_put(batch, shard)
-                v0_dev = jax.device_put(np.ascontiguousarray(v0b), shard)
-                batch_devices = len(batch_dev.sharding.device_set)
-                res = self.engine.run(batch_dev, v0_dev)
-                e = np.asarray(res.energy)             # (S, inner_runs)
-                sig = np.asarray(res.sigma)            # (S, inner, cb)
-                rec["t_engine"] += time.perf_counter() - t0
+                with phase("fabric.engine", rec, "t_engine", **where):
+                    batch_dev = jax.device_put(batch, shard)
+                    v0_dev = jax.device_put(np.ascontiguousarray(v0b), shard)
+                    batch_devices = len(batch_dev.sharding.device_set)
+                    res = self.engine.run(batch_dev, v0_dev)
+                    e = np.asarray(res.energy)             # (S, inner_runs)
+                    sig = np.asarray(res.sigma)            # (S, inner, cb)
                 dispatches += 1
 
                 # 4) sequential EXACT acceptance (monotone incumbents) in
                 # canonical (problem, tile) order — NOT die-major batch
                 # order, so results cannot depend on the mesh size
-                t0 = time.perf_counter()
-                best = e.argmin(axis=1)
-                cand_all = np.take_along_axis(
-                    sig, best[:, None, None], axis=1)[:, 0]
-                for slot, rows in accept:
-                    p, t = slot
-                    lo, hi, Jbb64, _, Jrows64 = tiles[slot]
-                    m = hi - lo
-                    cand = cand_all[rows]
-                    # gauge-fix the boundary ancilla to +1, trim to tile
-                    cand = (cand[:, 1:m + 1] *
-                            cand[:, :1]).astype(np.float64)
-                    cur = states[p][:, lo:hi]
-                    h = F[p][:, lo:hi] - cur @ Jbb64   # exact current field
-                    e_new = -np.einsum("rm,rm->r", h, cand) \
-                        - 0.5 * np.einsum("rm,mk,rk->r", cand, Jbb64, cand)
-                    e_old = -np.einsum("rm,rm->r", h, cur) \
-                        - 0.5 * np.einsum("rm,mk,rk->r", cur, Jbb64, cur)
-                    acc = np.flatnonzero(e_new < e_old - 1e-9)
-                    if len(acc):
-                        F[p][acc] += (cand[acc] - cur[acc]) @ Jrows64
-                        states[p][np.ix_(acc, np.arange(lo, hi))] = cand[acc]
-                rec["t_accept"] += time.perf_counter() - t0
+                with phase("fabric.accept", rec, "t_accept", **where):
+                    best = e.argmin(axis=1)
+                    cand_all = np.take_along_axis(
+                        sig, best[:, None, None], axis=1)[:, 0]
+                    for slot, rows in accept:
+                        p, t = slot
+                        lo, hi, Jbb64, _, Jrows64 = tiles[slot]
+                        m = hi - lo
+                        cand = cand_all[rows]
+                        # gauge-fix the boundary ancilla to +1, trim to tile
+                        cand = (cand[:, 1:m + 1] *
+                                cand[:, :1]).astype(np.float64)
+                        cur = states[p][:, lo:hi]
+                        # exact current field
+                        h = F[p][:, lo:hi] - cur @ Jbb64
+                        e_new = -np.einsum("rm,rm->r", h, cand) - 0.5 * \
+                            np.einsum("rm,mk,rk->r", cand, Jbb64, cand)
+                        e_old = -np.einsum("rm,rm->r", h, cur) - 0.5 * \
+                            np.einsum("rm,mk,rk->r", cur, Jbb64, cur)
+                        acc = np.flatnonzero(e_new < e_old - 1e-9)
+                        if len(acc):
+                            F[p][acc] += (cand[acc] - cur[acc]) @ Jrows64
+                            states[p][np.ix_(acc, np.arange(lo, hi))] = \
+                                cand[acc]
             rec["t_total"] = time.perf_counter() - t_sweep0
             sweeps_ledger.append(rec)
 

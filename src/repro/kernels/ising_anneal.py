@@ -157,6 +157,7 @@ def fused_anneal_kernel(J, v0, *, dev: DeviceModel, pert: PerturbationConfig,
     out = pl.pallas_call(
         kernel,
         grid=grid,
+        name="fused_anneal_kernel",   # the kernel's name in device traces
         in_specs=[
             pl.BlockSpec((1, Np, Np), lambda p, r: (p, 0, 0)),      # J_p
             pl.BlockSpec((1, block_r, Np), lambda p, r: (p, r, 0)),

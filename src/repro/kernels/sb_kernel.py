@@ -144,6 +144,7 @@ def fused_sb_kernel(Jc, x0, y0, *, variant: str = "bSB", n_steps: int = 400,
     out = pl.pallas_call(
         kernel,
         grid=grid,
+        name="sb_anneal_kernel",   # the kernel's name in device traces
         in_specs=[
             pl.BlockSpec((1, Np, Np), lambda p, r: (p, 0, 0)),      # Jc_p
             pl.BlockSpec((1, block_r, Np), lambda p, r: (p, r, 0)),  # x0

@@ -58,6 +58,7 @@ import numpy as np
 from ..api.registry import get_solver
 from ..api.suite import ProblemSuite
 from ..distributed.fault_tolerance import StragglerDetector
+from ..tracing import span
 
 log = logging.getLogger("repro.serve.resilience")
 
@@ -228,7 +229,9 @@ class FlushExecutor:
     ``(outcomes, partial_reports, dispatches)``: outcomes aligned with
     ``reqs``, the valid-row partial ``SolveReport``s (tagged with
     per-problem ``solver_by_problem``/``degraded`` meta so streamed merges
-    keep provenance), and the device dispatches actually issued.
+    keep provenance), and the device dispatches actually issued. ``flush``
+    is the id the flush's ``serve.dispatch`` and ``serve.validate`` spans
+    carry.
     """
 
     def __init__(self, policy: ResiliencePolicy, primary: Callable,
@@ -275,12 +278,12 @@ class FlushExecutor:
         return len(self._tiers) - 1
 
     # -- public entry ------------------------------------------------------
-    def execute(self, reqs):
+    def execute(self, reqs, flush: Optional[int] = None):
         outcomes: list[Optional[FlushOutcome]] = [None] * len(reqs)
         partials: list = []
         dispatches = [0]
         self._run(list(enumerate(reqs)), 0, False, 0,
-                  outcomes, partials, dispatches)
+                  outcomes, partials, dispatches, flush)
         for k, o in enumerate(outcomes):      # belt-and-braces: no request
             if o is None:                     # may leave without an outcome
                 outcomes[k] = FlushOutcome(
@@ -289,7 +292,7 @@ class FlushExecutor:
 
     # -- supervision core --------------------------------------------------
     def _run(self, items, tier, rescued, vdepth,
-             outcomes, partials, dispatches) -> None:
+             outcomes, partials, dispatches, flush) -> None:
         """Solve ``items`` (list of (position, request)) at the first
         allowed tier >= ``tier``; recurse on failure (bisection / fallback)
         and on validation rejects."""
@@ -304,7 +307,7 @@ class FlushExecutor:
         name = self._tiers[tier]
         reqs = [r for _, r in items]
         try:
-            rep, attempts = self._attempt(solver, name, reqs, tier)
+            rep, attempts = self._attempt(solver, name, reqs, tier, flush)
         except Exception as e:
             if len(items) > 1:
                 # bisect: isolate the poisoned request(s) instead of
@@ -313,14 +316,14 @@ class FlushExecutor:
                     self.bisections += 1
                 mid = len(items) // 2
                 self._run(items[:mid], tier, True, 0,
-                          outcomes, partials, dispatches)
+                          outcomes, partials, dispatches, flush)
                 self._run(items[mid:], tier, True, 0,
-                          outcomes, partials, dispatches)
+                          outcomes, partials, dispatches, flush)
                 return
             # singleton: escalate down the fallback chain
             if tier + 1 < len(self._tiers):
                 self._run(items, tier + 1, True, 0,
-                          outcomes, partials, dispatches)
+                          outcomes, partials, dispatches, flush)
             else:
                 self._fail_items(items, outcomes, FlushFailed(
                     f"request failed on every tier; last error from "
@@ -329,10 +332,12 @@ class FlushExecutor:
 
         dispatches[0] += rep.dispatches
         if self.policy.validate:
-            ok = [validate_row(r.problem, rep.energies[k], rep.best_sigma[k],
-                               self.policy.validate_atol,
-                               self.policy.validate_rtol)
-                  for k, r in enumerate(reqs)]
+            with span("serve.validate", flush=flush, rows=len(reqs)):
+                ok = [validate_row(r.problem, rep.energies[k],
+                                   rep.best_sigma[k],
+                                   self.policy.validate_atol,
+                                   self.policy.validate_rtol)
+                      for k, r in enumerate(reqs)]
         else:
             ok = [True] * len(reqs)
         good = [k for k, v in enumerate(ok) if v]
@@ -364,12 +369,12 @@ class FlushExecutor:
             if vdepth < self.policy.max_retries:
                 # same tier gets another chance (transient corruption)
                 self._run(bad_items, tier, True, vdepth + 1,
-                          outcomes, partials, dispatches)
+                          outcomes, partials, dispatches, flush)
             else:
                 # persistent corruption: this tier cannot be trusted with
                 # these requests — escalate
                 self._run(bad_items, tier + 1, True, 0,
-                          outcomes, partials, dispatches)
+                          outcomes, partials, dispatches, flush)
 
     def _fail_items(self, items, outcomes, err) -> None:
         with self._lock:
@@ -378,7 +383,7 @@ class FlushExecutor:
             outcomes[pos] = FlushOutcome(ok=False, error=err)
 
     # -- one solver tier: bounded retry with backoff -----------------------
-    def _attempt(self, solver, name, reqs, tier):
+    def _attempt(self, solver, name, reqs, tier, flush):
         suite = ProblemSuite([r.problem for r in reqs])
         budgets = [r.budget for r in reqs if r.budget is not None]
         budget = min(budgets) if budgets else None
@@ -392,7 +397,8 @@ class FlushExecutor:
             timeout = self._flush_timeout(reqs)
             t0 = time.monotonic()
             try:
-                rep = self._timed_solve(solver, suite, budget, timeout)
+                rep = self._timed_solve(solver, suite, budget, timeout,
+                                        flush, attempt)
             except SolverCrash:
                 breaker.trip()
                 raise
@@ -439,16 +445,22 @@ class FlushExecutor:
             return None
         return max(p.min_timeout_s, min(cands))
 
-    def _timed_solve(self, solver, suite, budget, timeout):
+    def _timed_solve(self, solver, suite, budget, timeout, flush, attempt):
         kw = dict(runs=self.runs, seed=self.seed, budget=budget,
                   block=self.block)
+
+        def solve(hedge):
+            with span("serve.dispatch", flush=flush, attempt=attempt,
+                      hedge=hedge):
+                return solver.solve(suite, **kw)
+
         if timeout is None:
-            return solver.solve(suite, **kw)
+            return solve(0)
         q: queue_mod.Queue = queue_mod.Queue()
 
-        def work():
+        def work(hedge=0):
             try:
-                q.put(("ok", solver.solve(suite, **kw)))
+                q.put(("ok", solve(hedge)))
             except BaseException as e:   # noqa: BLE001 — relayed to waiter
                 q.put(("err", e))
 
@@ -466,7 +478,7 @@ class FlushExecutor:
             # winner is bit-identical either way); first completion wins
             with self._lock:
                 self.hedges += 1
-            threading.Thread(target=work, daemon=True,
+            threading.Thread(target=work, args=(1,), daemon=True,
                              name="flush-hedge").start()
             outstanding = 2
             hard = time.monotonic() + timeout * self.policy.hedge_grace

@@ -53,6 +53,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import itertools
 import math
 import threading
 import time
@@ -66,6 +67,7 @@ from ..api.problem import Problem
 from ..api.registry import get_solver
 from ..api.report import SolveReport
 from ..api.suite import ProblemSuite
+from ..tracing import span
 from ..utils import (load_json_cache, load_sharded_json_cache,
                      store_json_cache, store_sharded_json_cache)
 from .faults import FaultInjector, FaultPlan, FaultySolver, corrupt_cache_entry
@@ -90,6 +92,9 @@ class ServeResult:
     #                               the flush that produced this result
     solver: str = ""              # tier that actually produced the answer
     attempts: int = 1             # dispatch attempts of the producing flush
+    queued_s: float = 0.0         # submit -> start of its flush
+    flush: Optional[int] = None   # id of its flush, as its flush's spans
+    #                               carry it (None: served from the cache)
 
     @property
     def best_energy(self) -> float:
@@ -327,7 +332,7 @@ class IsingService:
         self._draining = False
         self._thread: Optional[threading.Thread] = None
         self._started_at: Optional[float] = None
-        # counters (under _lock); latency/batch windows are bounded so a
+        # counters (under _lock); the latency window is bounded so a
         # long-running service's stats() stays O(window), not O(lifetime)
         self._submitted = 0
         self._completed = 0
@@ -340,8 +345,9 @@ class IsingService:
         self._shed_by_qos: collections.Counter = collections.Counter()
         self._degraded_admissions = 0
         self._cache_quarantined = 0
+        self._batched = 0            # requests over all flushes
         self._latencies: collections.deque = collections.deque(maxlen=100_000)
-        self._batch_sizes: collections.deque = collections.deque(maxlen=10_000)
+        self._flush_ids = itertools.count()
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "IsingService":
@@ -356,11 +362,11 @@ class IsingService:
             # the previous run's completions with this run's clock)
             self._submitted = self._completed = self._cache_hits = 0
             self._flushes = self._dispatches = self._errors = 0
+            self._batched = 0
             self._cancelled = self._shed = 0
             self._shed_by_qos.clear()
             self._degraded_admissions = self._cache_quarantined = 0
             self._latencies.clear()
-            self._batch_sizes.clear()
             self._partials = []
         self._thread = threading.Thread(target=self._worker,
                                         name="ising-serve", daemon=True)
@@ -534,8 +540,8 @@ class IsingService:
                 "cache_quarantined": self._cache_quarantined,
                 "flushes": self._flushes,
                 "dispatches": self._dispatches,
-                "mean_batch": (float(np.mean(self._batch_sizes))
-                               if self._batch_sizes else 0.0),
+                "mean_batch": (self._batched / self._flushes
+                               if self._flushes else 0.0),
                 "p50_latency_s": (float(np.percentile(lat, 50))
                                   if lat.size else 0.0),
                 "p95_latency_s": (float(np.percentile(lat, 95))
@@ -577,25 +583,37 @@ class IsingService:
     def _worker(self) -> None:
         while True:
             with self._lock:
-                if not self._running and not self._draining:
-                    return                 # stop(drain=False): leave the
-                now = time.monotonic()     # queue for stop() to fail
-                due, next_due = self._due_keys(now)
-                if not due:
-                    if not self._running:
-                        return
-                    timeout = (None if next_due is None
-                               else max(0.0, next_due - now))
-                    self._lock.wait(timeout)
-                    continue
-                batches = []
-                for key in due:
-                    reqs = self._pending.pop(key)
-                    # honor max_batch even on a burst: split oversize groups
-                    for i in range(0, len(reqs), self.max_batch):
-                        batches.append(reqs[i:i + self.max_batch])
+                batches = self._await_due()
+            if batches is None:
+                return
             for reqs in batches:           # dispatch OUTSIDE the lock —
                 self._solve_batch(reqs)    # new submits keep coalescing
+
+    def _await_due(self) -> Optional[list]:
+        """Under the lock: wait until some group is due, then pop it, split
+        into flushes of at most ``max_batch``; None when the worker ends.
+        One span covers the whole wait, however many wakeups it takes."""
+        with span("serve.wait", pending=sum(map(len,
+                                                self._pending.values()))):
+            while True:
+                if not self._running and not self._draining:
+                    return None            # stop(drain=False): leave the
+                now = time.monotonic()     # queue for stop() to fail
+                due, next_due = self._due_keys(now)
+                if due:
+                    break
+                if not self._running:
+                    return None
+                timeout = (None if next_due is None
+                           else max(0.0, next_due - now))
+                self._lock.wait(timeout)
+        batches = []
+        for key in due:
+            reqs = self._pending.pop(key)
+            # honor max_batch even on a burst: split oversize groups
+            for i in range(0, len(reqs), self.max_batch):
+                batches.append(reqs[i:i + self.max_batch])
+        return batches
 
     def _solve_batch(self, reqs: list[_Request]) -> None:
         with self._lock:
@@ -604,7 +622,20 @@ class IsingService:
             live = [r for r in reqs if not r.cancelled]
         if not live:
             return
-        outcomes, partials, dispatches = self._executor.execute(live)
+        fid = next(self._flush_ids)
+        started = time.monotonic()
+        with span("serve.flush", flush=fid, size=len(live),
+                  padded_n=live[0].key[0]):
+            outcomes, partials, dispatches = self._executor.execute(
+                live, flush=fid)
+            with span("serve.deliver", flush=fid):
+                self._finish_flush(live, fid, started, outcomes, partials,
+                                   dispatches)
+
+    def _finish_flush(self, live, fid, started, outcomes, partials,
+                      dispatches) -> None:
+        """Result objects, cache stores, counters and ticket resolves of
+        one flush."""
         now = time.monotonic()
         results: list[Optional[ServeResult]] = []
         for r, o in zip(live, outcomes):
@@ -616,7 +647,8 @@ class IsingService:
                 energies=o.energies, sigma=o.sigma,
                 latency_s=now - r.submitted, batch_size=len(live),
                 cached=False, budget=r.budget, degraded=o.degraded,
-                rescued=o.rescued, solver=o.solver, attempts=o.attempts))
+                rescued=o.rescued, solver=o.solver, attempts=o.attempts,
+                queued_s=started - r.submitted, flush=fid))
         for r, res in zip(live, results):
             # degraded results answer the caller but never poison the
             # cache: they were produced below the primary tier, and the
@@ -627,7 +659,7 @@ class IsingService:
             self._partials.extend(partials)
             self._flushes += 1
             self._dispatches += dispatches
-            self._batch_sizes.append(len(live))
+            self._batched += len(live)
             for r, res in zip(live, results):
                 if r.cancelled:
                     continue
