@@ -24,7 +24,7 @@ import numpy as np
 from ..tracing import span
 from .device_model import DeviceModel
 from .engine import AnnealEngine
-from .lfsr import lfsr_voltage_inits
+from .lfsr import expand_voltage_inits, lfsr_state_words, voltage_levels
 from .perturbation import PerturbationConfig, DEFAULT_PERTURBATION, NOMINAL
 
 _BACKEND_TO_PATH = {"jnp": "scan", "pallas": "fused", "auto": "auto"}
@@ -91,12 +91,14 @@ class IsingMachine:
             dev = dataclasses.replace(dev, n_spins=N)
 
         Jq = dev.quantize(J) if quantize else jnp.asarray(J)
-        with span("machine.lfsr_init", problems=P, runs=num_runs):
-            v0 = np.stack([
-                lfsr_voltage_inits(N, num_runs, seed=seed + 7919 * p,
-                                   vdd=dev.vdd, swing=dev.init_swing)
-                for p in range(P)
-            ])  # (P, R, N)
+        # problem p's runs are lfsr_voltage_inits(N, R, seed + 7919 p), bit
+        # for bit: the states go to the device, and it expands the voltages
+        with span("machine.lfsr_init", problems=P, runs=num_runs) as sp:
+            words = lfsr_state_words([seed + 7919 * p for p in range(P)],
+                                     N, num_runs)
+            levels = voltage_levels(dev.vdd, dev.init_swing)
+            v0 = expand_voltage_inits(words, levels.astype(np.float32), N)
+            sp.set_metadata(bytes=words.nbytes)
 
         # All paths dispatch through the AnnealEngine; it falls back to the
         # scan path automatically when noise/trajectory recording is asked

@@ -135,6 +135,10 @@ def test_engine_solve_spans_nest(engine_trace):
     init, = [s for s in _named(spans, "machine.lfsr_init")
              if s.counts["problems"] == 1]
     assert init.counts["runs"] == 8
+    # the LFSR states go to the device, 8 bytes per (problem, run, tile):
+    # both buckets (32 and 64 spins) are one tile wide
+    for init in _named(spans, "machine.lfsr_init"):
+        assert init.counts["bytes"] == init.counts["problems"] * 8 * 1 * 8
     # wait, then readback, after the dispatch, each once per bucket
     for run in _named(spans, "engine.run"):
         wait = min((s for s in _named(spans, "machine.wait")
