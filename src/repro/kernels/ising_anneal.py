@@ -157,7 +157,10 @@ def fused_anneal_kernel(J, v0, *, dev: DeviceModel, pert: PerturbationConfig,
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        name="fused_anneal_kernel",   # the kernel's name in device traces
+        # the kernel's name in device traces; the int8 path has its own, so
+        # a trace tells which variant ran
+        name=("fused_anneal_kernel_int8" if j_dtype == "int8"
+              else "fused_anneal_kernel"),
         in_specs=[
             pl.BlockSpec((1, Np, Np), lambda p, r: (p, 0, 0)),      # J_p
             pl.BlockSpec((1, block_r, Np), lambda p, r: (p, r, 0)),

@@ -20,39 +20,73 @@ def _gd_device(n, sweeps=3.75):
                        tau_leak_sweeps=float("inf"), noise_sigma=0.0)
 
 
-def _positive_jump_mass(traj):
-    diffs = np.diff(traj, axis=-1)
-    up = np.maximum(diffs, 0).sum()
-    down = -np.minimum(diffs, 0).sum()
-    return up / max(down, 1e-9)
+def _gd_replay(J, v0, dev):
+    """The unit-schedule Euler anneal replayed in numpy float32, one step at
+    a time: (spins before each step, spins after it), each (T, R, N)."""
+    dd = np.float32(dev.drive_eff * dev.dt)
+    v = v0.astype(np.float32)
+    Jf = J.astype(np.float32)
+    before, after = [], []
+    for _ in range(dev.n_steps):
+        q = np.where(v >= dev.threshold, 1.0, -1.0).astype(np.float32)
+        v = np.clip(v + (q @ Jf) * dd, np.float32(0.0),
+                    np.float32(dev.vdd))
+        before.append(q)
+        after.append(np.where(v >= dev.threshold, 1.0, -1.0))
+    return np.array(before, np.float64), np.array(after, np.float64), v
+
+
+def _energies(s, J):
+    return -0.5 * np.einsum("tri,ij,trj->tr", s, J, s)
 
 
 @given(st.integers(0, 2**31 - 1))
 @settings(max_examples=6, deadline=None)
 def test_gd_energy_monotone_in_fine_dt_limit(seed):
-    """Eq. (6) holds in CONTINUOUS time; the Euler discretization can raise
-    H transiently when several spins cross threshold in one step. The
-    correct discrete property: the positive-jump mass vanishes as dt -> 0
-    (and net descent always dominates)."""
+    """Eq. (6) holds in CONTINUOUS time for one spin crossing at a time;
+    the Euler discretization can raise H when several coupled spins cross
+    threshold in the same step, since none of them sees the others' flip.
+    Flipping the set F of spins s changes H by exactly
+    2 * sum_{i in F} s_i h_i - 2 * sum_{i, j in F} J_ij s_i s_j, with h the
+    fields before the step.
+
+    What the anneal guarantees is per step, at every dt: each spin that
+    flips moves along its own field (s_i h_i < 0 before the flip), so a
+    step that flips one spin lowers H by 2 |h_i| >= 2, and H rises only in
+    a step that flips several at once. It does NOT guarantee that the
+    mass of those rises shrinks as dt does. The LFSR init puts every
+    voltage at the same distance from threshold and the fields are
+    integers, so spins under equal opposing fields cross at the same
+    instant even in continuous time, at any dt; and a finer dt follows a
+    different path, which can meet such a tie that a coarser one missed
+    (seed 55639: rise mass 0, 0.0087, 0.0284 at 2, 8, 32 substeps)."""
     n = 24
     ps = problem_set(n, 0.5, 1, seed=seed % 100000)
-    v0 = lfsr_voltage_inits(n, 4, seed=seed % 999)[None]
-    masses = []
+    J = np.asarray(ps.J)[0].astype(np.float64)
+    v0 = lfsr_voltage_inits(n, 4, seed=seed % 999)
     for substeps in (2, 8, 32):
         dev = dataclasses.replace(_gd_device(n, sweeps=2.0),
                                   substeps=substeps)
-        res = anneal(jnp.asarray(ps.J), jnp.asarray(v0), dev, NOMINAL,
+        res = anneal(jnp.asarray(ps.J), jnp.asarray(v0[None]), dev, NOMINAL,
                      record_every=1)
-        traj = np.asarray(res.energy_traj)
-        masses.append(_positive_jump_mass(traj))
+        traj = np.asarray(res.energy_traj)[0].T            # (T, R)
+        before, after, v_final = _gd_replay(J, v0, dev)
+        # the replay is the program's anneal, bit for bit
+        np.testing.assert_array_equal(v_final, np.asarray(res.v_final)[0])
+        np.testing.assert_array_equal(_energies(after, J), traj)
+        flipped = before != after
+        h = before @ J
+        # every flip moves a spin along its own field
+        assert np.all(before * h < 0, where=flipped)
+        n_flips = flipped.sum(axis=-1)
+        dH = _energies(after, J) - _energies(before, J)
+        one = n_flips == 1
+        np.testing.assert_array_equal(
+            dH[one], -2 * np.abs(h * flipped).sum(axis=-1)[one])
+        # a rise needs several spins flipping in the same step
+        assert np.all(n_flips[dH > 0] >= 2), substeps
         # descent always dominates: final well below initial
-        assert traj[..., -1].mean() < traj[..., 0].mean()
-    # Trend check with a small absolute floor: a lucky coarse-dt run can land
-    # at exactly zero jump mass, while the fine-dt run keeps a ~1e-2 residue
-    # from threshold-crossing quantization — still "vanishing", not a
-    # violation of Eq. (6).
-    assert masses[-1] <= max(masses[0], 0.01) + 1e-9, masses
-    assert masses[-1] < 0.05, f"fine-dt positive-jump mass {masses[-1]}"
+        assert traj[-1].mean() < traj[0].mean()
 
 
 def test_gd_reaches_local_minima():

@@ -290,16 +290,24 @@ def _pallas_names(jaxpr) -> list:
     return names
 
 
-def test_kernels_carry_their_own_names():
+@pytest.mark.parametrize("j_dtype,kernel", [
+    ("float32", "fused_anneal_kernel"), ("bfloat16", "fused_anneal_kernel"),
+    ("int8", "fused_anneal_kernel_int8")])
+def test_kernels_carry_their_own_names(j_dtype, kernel):
     from repro.core.device_model import DeviceModel
-    from repro.core.perturbation import DEFAULT_PERTURBATION
+    from repro.core.perturbation import DEFAULT_PERTURBATION, NOMINAL
     from repro.kernels.ising_anneal import fused_anneal_kernel
     from repro.kernels.sb_kernel import fused_sb_kernel
+    # int8 runs only under the unit schedule: no perturbation, no leakage
+    unit = j_dtype == "int8"
+    dev = DeviceModel(n_spins=8)
+    if unit:
+        dev = dataclasses.replace(dev, tau_leak_sweeps=float("inf"))
     z = jnp.zeros((1, 8, 8), jnp.float32)
     anneal = jax.make_jaxpr(lambda J, v: fused_anneal_kernel(
-        J, v, dev=DeviceModel(n_spins=8), pert=DEFAULT_PERTURBATION,
-        block_r=8))(z, z)
+        J, v, dev=dev, pert=NOMINAL if unit else DEFAULT_PERTURBATION,
+        block_r=8, j_dtype=j_dtype))(z, z)
     sb = jax.make_jaxpr(lambda J, x, y: fused_sb_kernel(
         J, x, y, n_steps=4, block_r=8))(z, z, z)
-    assert _pallas_names(anneal.jaxpr) == ["fused_anneal_kernel"]
+    assert _pallas_names(anneal.jaxpr) == [kernel]
     assert _pallas_names(sb.jaxpr) == ["sb_anneal_kernel"]
