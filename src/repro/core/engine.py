@@ -259,14 +259,16 @@ class AnnealEngine:
                 # matches this lookup (the probe J is always integer levels).
                 self.autotune(P, R, N, j_dtype=run_j_dtype)
             plan = self.plan(P, R, N, J=J, needs_scan=needs_scan)
+            from ..kernels import ops as kops
             sp.set_metadata(path=plan.path, block_r=plan.block_r,
-                            j_dtype=plan.j_dtype)
+                            j_dtype=plan.j_dtype,
+                            dies_per_tile=(kops.tile_dies(J)
+                                           if plan.path == "fused" else 1))
 
             if plan.path == "scan":
                 return anneal(J, v0, dev, self.perturbation, key=key,
                               record_every=record_every)
 
-            from ..kernels import ops as kops
             v, sigma, energy = kops.fused_anneal(
                 J, v0, dev, self.perturbation, interpret=plan.interpret,
                 block_r=plan.block_r, j_dtype=plan.j_dtype)

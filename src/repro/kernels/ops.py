@@ -17,7 +17,7 @@ from ..core.binarize import sign_pm1
 from ..core.device_model import DeviceModel
 from ..core.hamiltonian import ising_energy
 from ..core.perturbation import PerturbationConfig
-from .ising_anneal import DEFAULT_BLOCK_R, fused_anneal_kernel
+from .ising_anneal import DEFAULT_BLOCK_R, dies_per_tile, fused_anneal_kernel
 
 
 def fused_anneal(J, v0, dev: DeviceModel, pert: PerturbationConfig,
@@ -65,11 +65,20 @@ def _batch_split(J):
     return sh.mesh, sh.spec[0]
 
 
+def tile_dies(J) -> int:
+    """Dies per kernel tile for the batch J (P,N,N): a batch sharded over
+    devices is packed slice by slice, so its per-device problem count
+    decides."""
+    P = J.sharding.shard_shape(J.shape)[0] if _batch_split(J) else J.shape[0]
+    return dies_per_tile(P, J.shape[-1])
+
+
 @functools.lru_cache(maxsize=16)
 def _per_shard(mesh, axis, dev, pert, block_r, j_dtype, interpret):
     """The kernel run on each device's slice of the problem axis. XLA
     cannot partition a Mosaic kernel itself, and problems never interact,
-    so each die anneals exactly the sub-instances it holds."""
+    so each die anneals exactly the sub-instances it holds, packing them
+    two a tile on its own slice."""
     kernel = functools.partial(fused_anneal_kernel, dev=dev, pert=pert,
                                block_r=block_r, j_dtype=j_dtype,
                                interpret=interpret)

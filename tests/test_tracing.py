@@ -292,7 +292,11 @@ def _pallas_names(jaxpr) -> list:
 
 @pytest.mark.parametrize("j_dtype,kernel", [
     ("float32", "fused_anneal_kernel"), ("bfloat16", "fused_anneal_kernel"),
-    ("int8", "fused_anneal_kernel_int8")])
+    ("int8", "fused_anneal_kernel_int8"),
+    # two problems of at most 64 spins share a tile
+    ("float32", "fused_anneal_kernel_x2"),
+    ("bfloat16", "fused_anneal_kernel_x2"),
+    ("int8", "fused_anneal_kernel_int8_x2")])
 def test_kernels_carry_their_own_names(j_dtype, kernel):
     from repro.core.device_model import DeviceModel
     from repro.core.perturbation import DEFAULT_PERTURBATION, NOMINAL
@@ -304,10 +308,32 @@ def test_kernels_carry_their_own_names(j_dtype, kernel):
     if unit:
         dev = dataclasses.replace(dev, tau_leak_sweeps=float("inf"))
     z = jnp.zeros((1, 8, 8), jnp.float32)
+    zp = jnp.zeros((2 if kernel.endswith("_x2") else 1, 8, 8), jnp.float32)
     anneal = jax.make_jaxpr(lambda J, v: fused_anneal_kernel(
         J, v, dev=dev, pert=NOMINAL if unit else DEFAULT_PERTURBATION,
-        block_r=8, j_dtype=j_dtype))(z, z)
+        block_r=8, j_dtype=j_dtype))(zp, zp)
     sb = jax.make_jaxpr(lambda J, x, y: fused_sb_kernel(
         J, x, y, n_steps=4, block_r=8))(z, z, z)
     assert _pallas_names(anneal.jaxpr) == [kernel]
     assert _pallas_names(sb.jaxpr) == ["sb_anneal_kernel"]
+
+
+@pytest.mark.parametrize("path,problems,dies", [
+    ("fused", 2, 2), ("fused", 1, 1), ("scan", 2, 1)])
+def test_engine_run_carries_dies_per_tile(tmp_path, path, problems, dies):
+    """``engine.run`` records how many dies each kernel tile anneals: two
+    small problems share one on the fused path; the scan path has none."""
+    from repro.core.device_model import DeviceModel
+    from repro.core.engine import AnnealEngine
+    from repro.core.lfsr import lfsr_voltage_inits
+    dev = DeviceModel(n_spins=16, anneal_sweeps=0.25)
+    eng = AnnealEngine(device=dev, path=path)
+    J = jnp.asarray(np.stack([Problem.random_qubo(16, 0.5, seed=i).levels
+                              for i in range(problems)]), jnp.float32)
+    v0 = jnp.asarray(np.stack([lfsr_voltage_inits(16, 8, seed=i)
+                               for i in range(problems)]))
+    eng.run(J, v0).v_final.block_until_ready()          # compile first
+    _, spans = _record(tmp_path, lambda: eng.run(J, v0))
+    run, = _named(spans, "engine.run")
+    assert run.counts["path"] == path
+    assert run.counts["dies_per_tile"] == dies
