@@ -16,10 +16,10 @@ import traceback
 
 from repro.utils import enable_compile_cache
 
-from . import (device_robustness, fabric_scaling, fig4_success,
-               fig4_trajectories, fig5_sr_density, fig5_tts,
-               kernel_throughput, roofline_bench, serve_chaos, serve_fleet,
-               serve_throughput, solver_matrix, table2_ets, workloads)
+from . import (device_robustness, fig4_success, fig4_trajectories,
+               fig5_sr_density, fig5_tts, kernel_throughput, roofline_bench,
+               serve_chaos, serve_fleet, serve_throughput, solver_matrix,
+               table2_ets, workloads)
 
 ALL = {
     "fig4_trajectories": fig4_trajectories.run,
@@ -33,7 +33,6 @@ ALL = {
     "serve_throughput": serve_throughput.run,
     "serve_chaos": serve_chaos.run,
     "serve_fleet": serve_fleet.run,
-    "fabric_scaling": fabric_scaling.run,
     "device_robustness": device_robustness.run,
     "workloads": workloads.run,
 }

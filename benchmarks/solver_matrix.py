@@ -91,12 +91,12 @@ def run(full: bool = False):
 
     # -- N=128 decomposition row: chip-lns vs fabric-jax ------------------
     # The first N > 64 line in the perf trajectory: both decomposition
-    # tiers on one 128-spin instance at identical seeds/effort. chip-lns
-    # anneals one block per dispatch position; fabric-jax one dispatch per
-    # color phase — the ledger shapes are pinned here, the wall/energy
-    # columns track the trajectory.
+    # settings of the one LNS on one 128-spin instance at identical
+    # seeds/effort. chip-lns (one color, one die) is one dispatch per
+    # sweep; fabric-jax one dispatch per color phase — the ledger shapes
+    # are pinned here, the wall/energy columns track the trajectory.
     import numpy as np
-    from repro.core.engine import lns_blocks
+    from repro.distributed.fabric import lns_blocks
     p128 = Problem.maxcut(128, density=0.5, seed=717)
     lns_runs, lns_outer = (8, 8) if full else (4, 4)
     dec = {}

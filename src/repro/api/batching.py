@@ -6,9 +6,8 @@ multiple of the chip block and stacking same-pad problems into one
 ``(P, n_pad, n_pad)`` device batch. That planning used to be duplicated in
 three places — ``ProblemSuite.buckets`` (suite stacking), the registry's
 ``_bucketed_report`` (trim/reorder of bucket results back into suite
-order), and the oracle's batched tabu-jax refresh — plus a fourth ad-hoc
-variant in ``core.engine.BlockLNS`` (chip-lns sub-instance stacking). All
-four now route through this module:
+order), and the oracle's batched tabu-jax refresh. All three now route
+through this module:
 
   * :func:`plan_buckets` — pure planning: group problem indices by padded
     size into a :class:`BatchPlan` (no arrays touched). The number of
@@ -118,8 +117,7 @@ def pad_stack(mats: Sequence[np.ndarray], n_pad: int) -> np.ndarray:
 
     Each element of ``mats`` is either one ``(m, m)`` coupling matrix
     (contributes one batch row — the suite path) or an ``(R, m, m)`` stack
-    (contributes R rows — the chip-lns sub-instance path, where every
-    restart carries its own boundary field). ``m <= n_pad``; the padded
+    (contributes R rows, one per restart). ``m <= n_pad``; the padded
     region stays exactly zero.
     """
     rows = []

@@ -567,32 +567,48 @@ class SBJaxSolver:
             warmup=self.warmup)
 
 
-@register_solver("chip-lns", needs_oracle=True, exact=False, device="jax")
-class ChipLNSSolver:
-    """Multi-chip decomposition: large-neighborhood search over one-die
-    blocks (``core.engine.BlockLNS``) — the registry's only solver WITHOUT
-    a capacity limit that still runs on the chip's anneal path.
+@register_solver("fabric-jax", needs_oracle=True, exact=False, device="jax")
+class FabricSolver:
+    """Large-neighborhood search over a mesh of 64-spin dies
+    (``distributed.fabric.FabricLNS``) — no capacity limit, on the chip's
+    anneal path.
 
     Problems with N <= ``block`` are delegated verbatim to the direct
     engine solve (same machine, same seeds — bit-identical energies), so
-    'chip-lns' is a strict superset of 'engine'. Larger problems iterate:
-    clamp all but one (block-1)-spin sub-block, anneal the free block plus
-    one boundary-field ancilla as exactly one die, and accept candidate
-    block configurations by exact float64 delta energy — every (problem,
-    restart, block) sub-instance of an outer sweep rides ONE device
-    dispatch. ``runs`` is the number of independent LNS restarts;
-    ``budget`` multiplies the outer sweep count (the engine delegation for
-    small problems keeps its own default anneal length).
+    the solver is a strict superset of 'engine'. Larger problems iterate:
+    clamp all but one (block-1)-spin tile, anneal the tile plus one
+    boundary-field ancilla as exactly one die, and accept candidate tile
+    configurations by exact float64 delta energy (monotone incumbents).
+    'fabric-jax' 2-colors the tile grid and anneals every tile of a color
+    class concurrently across the device mesh: ``n_colors x
+    outer_sweeps`` engine dispatches per solve — never one per tile — with
+    the clamped-spin boundary fields computed on-mesh as sharded ``J_tile
+    @ s`` row-sums. Level-space fields are integer-exact in float32, so
+    results are bit-identical for every mesh size. ``runs`` is the number
+    of independent LNS restarts; ``budget`` multiplies the outer sweep
+    count (the engine delegation for small problems keeps its own default
+    anneal length).
+
+    ``mesh_devices`` picks how many local devices form the fabric
+    (default: all — 1 on an unforced host; run under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for an 8-die
+    fabric). ``meta['fabric']`` carries the per-color occupancy/timing
+    ledger.
     """
+
+    #: color phases per outer sweep (the checkerboard)
+    colors = 2
 
     def __init__(self, backend: str = "auto", inner_runs: int = 8,
                  outer_sweeps: Optional[int] = None,
                  anneal_sweeps: Optional[float] = None,
+                 mesh_devices: Optional[int] = None,
                  warmup: bool = False):
         self.backend = backend
         self.inner_runs = inner_runs
         self.outer_sweeps = outer_sweeps
         self.anneal_sweeps = anneal_sweeps
+        self.mesh_devices = mesh_devices
         self.warmup = warmup
 
     def _engine(self):
@@ -609,129 +625,14 @@ class ChipLNSSolver:
     def solve(self, suite, runs: int = 64, seed: int = 0,
               budget: Optional[float] = None,
               block: int = CHIP_BLOCK) -> SolveReport:
-        from ..core.engine import BlockLNS, lns_blocks
-        suite = as_suite(suite)
-        wall = 0.0
-        # Delegation threshold: the direct engine can only take what BOTH
-        # the requested block and its own die cap allow — with block > 64
-        # the oversized problems must still decompose, not bounce off the
-        # engine's max_n check.
-        delegate_n = min(block, EngineSolver.caps.max_n or block)
-        small = [i for i, n in enumerate(suite.sizes) if n <= delegate_n]
-        big = [i for i, n in enumerate(suite.sizes) if n > delegate_n]
-
-        energies = [None] * len(suite)
-        sigmas = [None] * len(suite)
-        dispatches = 0
-        compile_s = 0.0
-        meta = {"block": block, "inner_runs": self.inner_runs,
-                "lns_problems": big}
-
-        if small:
-            sub = ProblemSuite([suite[i] for i in small])
-            rep = EngineSolver(backend=self.backend,
-                               warmup=self.warmup).solve(
-                sub, runs=runs, seed=seed, budget=None, block=delegate_n)
-            for k, i in enumerate(small):
-                energies[i] = rep.energies[k]
-                sigmas[i] = rep.best_sigma[k]
-            dispatches += rep.dispatches
-            compile_s += rep.compile_s
-            wall += rep.wall_s
-            meta["engine_plan"] = rep.meta.get("engine_plan")
-
-        if big:
-            n_blocks = max(len(lns_blocks(suite[i].n, delegate_n - 1))
-                           for i in big)
-            outer = self.outer_sweeps or max(4, 2 * n_blocks)
-            outer = search_effort(outer, runs, budget).iters
-            # the die is delegate_n, never the (possibly larger) pad block:
-            # block=128 must decompose onto real 64-spin dies, not anneal a
-            # 128-spin virtual chip the capability check exists to forbid
-            lns = BlockLNS(self._engine(), chip_block=delegate_n,
-                           inner_runs=self.inner_runs)
-            big_J = [suite[i].J_levels.astype(np.float64) for i in big]
-            if self.warmup:
-                # same compile/steady split as _bucketed_report: pay the
-                # trace on a discarded identical solve (deterministic
-                # seed), time the second
-                tw = time.time()
-                lns.solve(big_J, restarts=runs, outer_sweeps=outer,
-                          seed=seed + 104729)
-                t_first = time.time() - tw
-            t0 = time.time()
-            results, d = lns.solve(big_J, restarts=runs,
-                                   outer_sweeps=outer, seed=seed + 104729)
-            if self.warmup:
-                compile_s += max(0.0, t_first - (time.time() - t0))
-            dispatches += d
-            meta["outer_sweeps"] = outer
-            meta["lns_timings"] = lns.last_timings
-            meta["n_blocks"] = n_blocks
-            meta["init_energies"] = {}
-            for (e, s, e0), i in zip(results, big):
-                energies[i] = e
-                sigmas[i] = s[int(np.argmin(e))]
-                meta["init_energies"][i] = e0.tolist()
-            wall += time.time() - t0
-
-        # wall accumulates the component solve times, so warmup compile
-        # paid inside the engine delegation is never charged to the solve
-        return SolveReport(
-            solver=self.name, runs=runs, energies=energies,
-            best_sigma=sigmas, problem_hashes=suite.hashes,
-            sizes=suite.sizes, scales=tuple(p.scale for p in suite),
-            wall_s=wall, compile_s=compile_s, dispatches=dispatches,
-            meta=meta)
-
-
-@register_solver("fabric-jax", needs_oracle=True, exact=False, device="jax")
-class FabricSolver:
-    """Mesh-sharded checkerboard LNS — the virtual mega-fabric
-    (``distributed.fabric.FabricLNS``). No capacity limit.
-
-    Where 'chip-lns' anneals ONE block per color-less sweep position on a
-    single die, 'fabric-jax' 2-colors the tile grid and anneals every tile
-    of a color class concurrently across the device mesh: the dispatch
-    ledger is ``n_colors x outer_sweeps`` engine dispatches per solve —
-    never one per block — and the clamped-spin boundary fields are
-    computed on-mesh as sharded ``J_tile @ s`` row-sums (psum along the
-    tile row axis) instead of host gathers. Acceptance is the same exact
-    float64 delta-energy rule as BlockLNS (monotone incumbents), and
-    because level-space fields are integer-exact in float32, results are
-    bit-identical for every mesh size. Problems with N <= ``block``
-    delegate verbatim to the direct engine solve (bit-identical energies),
-    exactly like 'chip-lns'.
-
-    ``mesh_devices`` picks how many local devices form the fabric
-    (default: all — 1 on an unforced host; run under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for an 8-die
-    fabric). ``meta['fabric']`` carries the per-color occupancy/timing
-    ledger.
-    """
-
-    def __init__(self, backend: str = "auto", inner_runs: int = 8,
-                 outer_sweeps: Optional[int] = None,
-                 anneal_sweeps: Optional[float] = None,
-                 mesh_devices: Optional[int] = None,
-                 warmup: bool = False):
-        self.backend = backend
-        self.inner_runs = inner_runs
-        self.outer_sweeps = outer_sweeps
-        self.anneal_sweeps = anneal_sweeps
-        self.mesh_devices = mesh_devices
-        self.warmup = warmup
-
-    _engine = ChipLNSSolver._engine
-
-    def solve(self, suite, runs: int = 64, seed: int = 0,
-              budget: Optional[float] = None,
-              block: int = CHIP_BLOCK) -> SolveReport:
-        from ..core.engine import lns_blocks
-        from ..distributed.fabric import FabricLNS, fabric_mesh
+        from ..distributed.fabric import FabricLNS, fabric_mesh, lns_blocks
         suite = as_suite(suite)
         with span("registry.solve", problems=len(suite), runs=runs):
             wall = 0.0
+            # Delegation threshold: the direct engine can only take what
+            # BOTH the requested block and its own die cap allow — with
+            # block > 64 the oversized problems must still decompose, not
+            # bounce off the engine's max_n check.
             delegate_n = min(block, EngineSolver.caps.max_n or block)
             small = [i for i, n in enumerate(suite.sizes) if n <= delegate_n]
             big = [i for i, n in enumerate(suite.sizes) if n > delegate_n]
@@ -759,16 +660,22 @@ class FabricSolver:
             if big:
                 n_blocks = max(len(lns_blocks(suite[i].n, delegate_n - 1))
                                for i in big)
-                # same effort mapping as chip-lns so the two tiers compare at
-                # equal work: outer sweeps, restarts, inner runs all line up
                 outer = self.outer_sweeps or max(4, 2 * n_blocks)
                 outer = search_effort(outer, runs, budget).iters
+                # the die is delegate_n, never the (possibly larger) pad
+                # block: block=128 must decompose onto real 64-spin dies,
+                # not anneal a 128-spin virtual chip the capability check
+                # exists to forbid
                 mesh = fabric_mesh(self.mesh_devices)
                 lns = FabricLNS(self._engine(), mesh=mesh,
                                 chip_block=delegate_n,
-                                inner_runs=self.inner_runs)
+                                inner_runs=self.inner_runs,
+                                colors=self.colors)
                 big_J = [suite[i].J_levels.astype(np.float64) for i in big]
                 if self.warmup:
+                    # same compile/steady split as _bucketed_report: pay
+                    # the trace on a discarded identical solve
+                    # (deterministic seed), time the second
                     tw = time.time()
                     lns.solve(big_J, restarts=runs, outer_sweeps=outer,
                               seed=seed + 104729)
@@ -792,12 +699,32 @@ class FabricSolver:
                     meta["init_energies"][i] = e0.tolist()
                 wall += time.time() - t0
 
+            # wall accumulates the component solve times, so warmup compile
+            # paid inside the engine delegation is never charged to the solve
             return SolveReport(
                 solver=self.name, runs=runs, energies=energies,
                 best_sigma=sigmas, problem_hashes=suite.hashes,
                 sizes=suite.sizes, scales=tuple(p.scale for p in suite),
                 wall_s=wall, compile_s=compile_s, dispatches=dispatches,
                 meta=meta)
+
+
+@register_solver("chip-lns", needs_oracle=True, exact=False, device="jax")
+class ChipLNSSolver(FabricSolver):
+    """One color on one die of the fabric's LNS: every (problem, restart,
+    tile) sub-instance of an outer sweep rides ONE device dispatch, so the
+    dispatch ledger is one per outer sweep."""
+
+    colors = 1
+
+    def __init__(self, backend: str = "auto", inner_runs: int = 8,
+                 outer_sweeps: Optional[int] = None,
+                 anneal_sweeps: Optional[float] = None,
+                 warmup: bool = False):
+        super().__init__(backend=backend, inner_runs=inner_runs,
+                         outer_sweeps=outer_sweeps,
+                         anneal_sweeps=anneal_sweeps, mesh_devices=1,
+                         warmup=warmup)
 
 
 @register_solver("ode-jax", needs_oracle=True, exact=False, device="jax",

@@ -1,49 +1,53 @@
-"""Virtual mega-fabric: mesh-sharded checkerboard LNS at thousands of spins.
+"""Large-neighbourhood search past the 64-spin die, over a mesh of dies.
 
-``core.engine.BlockLNS`` breaks the 64-spin die limit by clamping all but
-one sub-block and annealing the free block on the die — but every block of
-every outer sweep rides ONE die: at N=2000 that is ~32 block-anneals a
-single chip must serialize per sweep, so per-sweep die occupancy grows
-linearly with problem size. This module is the software analogue of tiling
-many 64-spin chips into a larger fabric (the scaling move every multi-chip
-CMOS Ising paper — BRIM et al., PAPERS.md — treats as the real question):
+The die solves at most ``chip_block`` all-to-all spins. Larger problems are
+split into contiguous tiles of ``chip_block - 1`` free spins; a sub-solve
+clamps every spin outside one tile and anneals the tile plus ONE boundary
+ancilla whose coupling row carries the exact field of the clamped spins
+(``h_i = sum_{j not in tile} J_ij s_j``) — exactly one die program. The
+bias-free Z2 symmetry makes ancilla pinning unnecessary: candidates are
+gauge-fixed after the anneal. This module is the software analogue of
+tiling many 64-spin chips into a larger fabric (the scaling move every
+multi-chip CMOS Ising paper — BRIM et al., PAPERS.md — treats as the real
+question):
 
-* :class:`FabricLayout` blocks the spin index into contiguous tiles of at
-  most ``free_block`` (= 63) spins, 2-colors them checkerboard-style
-  (tile parity) and assigns tiles round-robin to the ``K`` dies of the
-  device mesh. All tiles of one color share no free spins, so every die
-  in a color class anneals its tiles CONCURRENTLY — one batched engine
-  dispatch per color phase, never one per block.
+* :class:`FabricLayout` blocks the spin index into the tiles of
+  :func:`lns_blocks`, colors them (two colors: checkerboard tile parity;
+  one color: every tile in one phase) and assigns tiles round-robin to the
+  ``K`` dies of the device mesh. Every die in a color class anneals its
+  tiles CONCURRENTLY — one batched engine dispatch per color phase, never
+  one per tile.
 
 * :class:`FieldExchange` keeps the full coupling matrix resident on the
   mesh, column-tile sharded, and computes the clamped-spin boundary
   fields as sharded ``J_tile @ s`` partial products psummed along the
   tile row axis (``shard_map`` over the ``fabric`` axis) — the halo
-  exchange of a chip fabric, replacing the host-side ``S @ J[:, blk]``
-  gathers that dominate BlockLNS at large N. J and sigma are integer
-  valued (DAC levels x +-1), so the float32 partial sums are EXACT
-  (|h| <= 15*N << 2^24) and the exchanged fields are bit-identical for
-  every mesh size.
+  exchange of a chip fabric, replacing host-side ``S @ J[:, blk]``
+  gathers. J and sigma are integer valued (DAC levels x +-1), so the
+  float32 partial sums are EXACT (|h| <= 15*N << 2^24) and the exchanged
+  fields are bit-identical for every mesh size.
 
-* :class:`FabricLNS` runs the checkerboard sweep: per color phase, fields
-  are exchanged once, every (die, tile, restart) sub-instance — a
-  ``free_block``-spin tile plus one boundary-field ancilla, exactly one
-  die program — is written into a PREBUILT batch template (the invariant
-  ``J_tile`` blocks are stamped once, only the ancilla row/col changes
-  per phase), and the whole color class anneals as one engine dispatch
-  sharded die-aligned across the mesh. Candidates are then accepted
-  sequentially per tile by EXACT float64 delta energy against the
-  current state (an incrementally-maintained full-field ledger), so the
-  per-restart incumbent is monotonically non-increasing — the same
-  acceptance contract as :class:`~repro.core.engine.BlockLNS`. Crucially
-  the acceptance loop runs in CANONICAL ``(problem, tile)`` order, never
-  in the die-major slot order of the batch: same-color tiles share no
-  free spins but are still coupled through J, so each acceptance shifts
-  the field ledger seen by later tiles — iterating in mesh-dependent
-  order would make acceptance decisions (and thus results) depend on
+* :class:`FabricLNS` runs the sweep: per color phase, fields are
+  exchanged once, every (die, tile, restart) sub-instance is written into
+  a PREBUILT batch template (the invariant ``J_tile`` blocks are stamped
+  once, only the ancilla row/col changes per phase), and the whole color
+  class anneals as one engine dispatch sharded die-aligned across the
+  mesh. Candidates are then accepted sequentially per tile by EXACT
+  float64 delta energy against the current state (an
+  incrementally-maintained full-field ledger), so the per-restart
+  incumbent is monotonically non-increasing. Crucially the acceptance
+  loop runs in CANONICAL ``(problem, tile)`` order, never in the
+  die-major slot order of the batch: same-color tiles share no free
+  spins but are still coupled through J, so each acceptance shifts the
+  field ledger seen by later tiles — iterating in mesh-dependent order
+  would make acceptance decisions (and thus results) depend on
   ``n_dies``. With the canonical order the mesh decides only WHERE
   candidates are generated, never what is accepted, and results are
   bit-identical across mesh sizes.
+
+The registry serves two settings of the one search: ``fabric-jax`` (two
+colors over every device of the mesh) and ``chip-lns`` (one color on one
+die: every tile of a sweep in one dispatch).
 
 Dispatch ledger: ``colors x outer_sweeps`` engine dispatches per solve
 (the anneal bursts that occupy dies), plus ``problems x colors x
@@ -91,29 +95,41 @@ def fabric_mesh(n_dies: Optional[int] = None) -> Mesh:
     return Mesh(np.asarray(devs[:k]), (FABRIC_AXIS,))
 
 
+def lns_blocks(n: int, free_block: int) -> list[np.ndarray]:
+    """Balanced contiguous partition of [0, n) into ceil(n/free_block)
+    blocks of at most ``free_block`` spins each."""
+    if free_block < 1:
+        raise ValueError(f"free_block must be >= 1, got {free_block}")
+    n_blocks = max(1, -(-n // free_block))
+    return [np.asarray(b) for b in np.array_split(np.arange(n), n_blocks)]
+
+
 @dataclasses.dataclass(frozen=True)
 class FabricLayout:
     """Tile grid of one problem over a ``n_dies``-die fabric.
 
-    Tiles are the contiguous balanced blocks of
-    :func:`repro.core.engine.lns_blocks` (at most ``free_block`` spins
-    each, so tile + boundary ancilla fits one die), colored by parity and
-    assigned round-robin within each color class, so every color phase
-    spreads its tiles evenly across all ``n_dies`` dies.
+    Tiles are the contiguous balanced blocks of :func:`lns_blocks` (at
+    most ``free_block`` spins each, so tile + boundary ancilla fits one
+    die), colored by parity (``colors=2``, the checkerboard) or all in one
+    phase (``colors=1``), and assigned round-robin within each color
+    class, so every color phase spreads its tiles evenly across all
+    ``n_dies`` dies.
     """
     n: int
     n_dies: int
     free_block: int
     tiles: tuple                      # tuple[np.ndarray] spin-index blocks
+    colors: int = 2
 
     @classmethod
-    def build(cls, n: int, n_dies: int,
-              free_block: int = 63) -> "FabricLayout":
-        from ..core.engine import lns_blocks
+    def build(cls, n: int, n_dies: int, free_block: int = 63,
+              colors: int = 2) -> "FabricLayout":
         if n_dies < 1:
             raise ValueError(f"n_dies must be >= 1, got {n_dies}")
+        if colors not in (1, 2):
+            raise ValueError(f"colors must be 1 or 2, got {colors}")
         return cls(n=int(n), n_dies=int(n_dies), free_block=int(free_block),
-                   tiles=tuple(lns_blocks(n, free_block)))
+                   tiles=tuple(lns_blocks(n, free_block)), colors=int(colors))
 
     @property
     def n_tiles(self) -> int:
@@ -121,8 +137,8 @@ class FabricLayout:
 
     @property
     def n_colors(self) -> int:
-        """2-coloring (checkerboard) once there is anything to alternate."""
-        return min(2, self.n_tiles)
+        """The requested colors, once there are that many tiles."""
+        return min(self.colors, self.n_tiles)
 
     def color_of(self, t: int) -> int:
         return t % self.n_colors
@@ -239,30 +255,31 @@ class FieldExchange:
 
 
 class FabricLNS:
-    """Checkerboard large-neighborhood search over a die mesh.
+    """Colored large-neighborhood search over a die mesh.
 
-    Same contract as :class:`repro.core.engine.BlockLNS` — ``solve``
-    minimizes level-space ``H = -0.5 s'Js`` and returns per-problem
-    ``(energies (R,), sigma (R, N), init_energies (R,))`` plus the engine
-    dispatch count — but all non-interacting tiles of a color phase
-    anneal concurrently across the mesh, per-sweep dispatches are
-    ``n_colors`` (never one per block), and the boundary fields feeding
-    the candidate anneals come from the sharded :class:`FieldExchange`
-    instead of host matmuls. Acceptance stays sequential, float64-exact,
-    and in canonical (problem, tile) order regardless of which die
-    generated each candidate (per-restart incumbents are monotone), so
-    the mesh size cannot change the result — only where the work runs.
+    ``solve`` minimizes level-space ``H = -0.5 s'Js`` and returns
+    per-problem ``(energies (R,), sigma (R, N), init_energies (R,))`` plus
+    the engine dispatch count. All tiles of a color phase anneal
+    concurrently across the mesh, per-sweep dispatches are ``n_colors``
+    (never one per tile), and the boundary fields feeding the candidate
+    anneals come from the sharded :class:`FieldExchange`. Acceptance is
+    sequential, float64-exact, and in canonical (problem, tile) order
+    regardless of which die generated each candidate (per-restart
+    incumbents are monotone), so the mesh size cannot change the result —
+    only where the work runs. ``colors`` is 2 (checkerboard) or 1 (every
+    tile of a sweep in one phase, the ``chip-lns`` setting).
 
     After ``solve``, ``self.ledger`` holds the occupancy/timing record
     the registry surfaces as ``meta['fabric']``.
     """
 
     def __init__(self, engine, mesh: Optional[Mesh] = None,
-                 chip_block: int = 64, inner_runs: int = 8):
+                 chip_block: int = 64, inner_runs: int = 8, colors: int = 2):
         self.engine = engine
         self.mesh = mesh if mesh is not None else fabric_mesh()
         self.chip_block = chip_block
         self.inner_runs = inner_runs
+        self.colors = colors
         self.n_dies = int(self.mesh.shape[FABRIC_AXIS])
         self.ledger: dict = {}
 
@@ -273,8 +290,8 @@ class FabricLNS:
         with every ``J_tile`` block already stamped (per phase only the
         ancilla row/col is rewritten)."""
         cb = self.chip_block
-        layouts = [FabricLayout.build(J.shape[0], self.n_dies, cb - 1)
-                   for J in Js]
+        layouts = [FabricLayout.build(J.shape[0], self.n_dies, cb - 1,
+                                      self.colors) for J in Js]
         exchangers = [FieldExchange(J, self.mesh) for J in Js]
         n_colors = max(l.n_colors for l in layouts)
         colors = []
@@ -339,7 +356,8 @@ class FabricLNS:
         cb = self.chip_block
         rng = np.random.default_rng(seed)
         Js = [np.asarray(J, dtype=np.float64) for J in J_list]
-        # same init stream as BlockLNS: seed-equal solves start equal
+        # one init stream for every color count: seed-equal solves start
+        # equal
         states = [rng.choice([-1.0, 1.0], size=(restarts, J.shape[0]))
                   for J in Js]
 
@@ -395,9 +413,13 @@ class FabricLNS:
                              - Sb @ Jbb64)
                         batch[rows, 0, 1:m + 1] = h
                         batch[rows, 1:m + 1, 0] = h
+                    # phase LFSR seed: seed + 7919(sweep+1), plus
+                    # 104729(c+1) per color phase when there are two
+                    # colors; one color keeps the bare sweep stream
                     v0 = lfsr_voltage_inits(
                         cb, self.inner_runs,
-                        seed=seed + 7919 * (sweep + 1) + 104729 * (c + 1))
+                        seed=seed + 7919 * (sweep + 1)
+                        + 104729 * (c + 1) * (self.colors - 1))
                     v0b = np.broadcast_to(v0, (batch.shape[0],) + v0.shape)
 
                 # 3) ONE die-aligned engine dispatch for the color class

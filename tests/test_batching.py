@@ -138,8 +138,9 @@ def test_pad_stack_shapes_and_zero_padding():
 
 
 def test_chip_lns_stacking_unchanged_by_pad_stack_route():
-    """chip-lns (BlockLNS) now builds its sub-instance batch through
-    pad_stack — deterministic end-to-end parity pins the route."""
+    """chip-lns (one color on one die of the fabric's LNS) builds its
+    sub-instance batch from a prebuilt template — deterministic
+    end-to-end parity pins the route."""
     suite = ProblemSuite([Problem.random_qubo(70, 0.4, seed=11)])
     kw = dict(inner_runs=2, outer_sweeps=2, anneal_sweeps=0.37)
     r1 = get_solver("chip-lns", **kw).solve(suite, runs=2, seed=5)

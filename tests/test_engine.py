@@ -245,6 +245,31 @@ def test_chip_lns_beyond_die_deterministic_and_monotone():
                for a, b in zip(rep.energies, rep3.energies))
 
 
+def test_chip_lns_answers_pinned():
+    """chip-lns returns, bit for bit, the per-run energies and best spins
+    it returned as a one-color dispatch per sweep of its own search (the
+    suite and options of the test above)."""
+    from repro.api import Problem, ProblemSuite, get_solver
+
+    suite = ProblemSuite([Problem.maxcut(96, 0.3, seed=1),
+                          Problem.random_qubo(128, 0.2, seed=2)])
+    rep = get_solver("chip-lns", inner_runs=4, anneal_sweeps=1.0).solve(
+        suite, runs=4, seed=5, budget=0.5)
+    want_e = [[-2853.0, -3093.0, -2733.0, -2871.0],
+              [-3788.0, -3296.0, -3372.0, -3736.0]]
+    want_s = ["+++++--+-+-+--++++---+-++++++-+-------++++-++---+--+-----++--"
+              "++-+---+++--+++-+++--+++-++---++--+",
+              "-++++-++-+++--+-+++++++-+-----+-----++-+++++-+---+-+--------"
+              "-+--++-+-+--+++-+---++++-+-+++----++++-++++--+-+----++--+--+"
+              "-+---+-+"]
+    for e, s, we, ws in zip(rep.energies, rep.best_sigma, want_e, want_s):
+        np.testing.assert_array_equal(np.asarray(e, np.float64), we)
+        assert s.dtype == np.int8
+        assert "".join("+" if x > 0 else "-" for x in s) == ws
+    assert rep.meta["init_energies"] == {0: [93.0, 237.0, 57.0, 473.0],
+                                         1: [-32.0, 186.0, 46.0, 336.0]}
+
+
 def test_single_die_solvers_reject_padded_virtual_chips():
     """The capability check fires BEFORE bucketing pads N=96 to a 128-spin
     virtual chip nobody manufactured."""
@@ -260,7 +285,7 @@ def test_single_die_solvers_reject_padded_virtual_chips():
 
 
 def test_lns_blocks_partition():
-    from repro.core.engine import lns_blocks
+    from repro.distributed.fabric import lns_blocks
     blocks = lns_blocks(128, 63)
     assert sum(len(b) for b in blocks) == 128
     assert max(len(b) for b in blocks) <= 63

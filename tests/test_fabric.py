@@ -6,9 +6,9 @@ import pytest
 
 from repro.api import Problem
 from repro.api.registry import get_solver
-from repro.core.engine import AnnealEngine, BlockLNS, lns_blocks
+from repro.core.engine import AnnealEngine
 from repro.distributed.fabric import (FabricLayout, FabricLNS,
-                                      FieldExchange, fabric_mesh)
+                                      FieldExchange, fabric_mesh, lns_blocks)
 from repro.problems.gset import (cut_from_energy, dump_gset, gset_problem,
                                  parse_gset, random_gset)
 
@@ -43,6 +43,16 @@ def test_layout_single_tile_has_one_color():
     lay = FabricLayout.build(40, n_dies=2)
     assert lay.n_tiles == 1
     assert lay.n_colors == 1
+
+
+def test_layout_one_color_puts_every_tile_in_phase_zero():
+    lay = FabricLayout.build(200, n_dies=1, colors=1)
+    assert lay.n_colors == 1
+    assert lay.color_tiles(0) == list(range(lay.n_tiles))
+    # one die, one phase: the slot order is the tile order
+    assert lay.die_color_tiles(0) == [(0, list(range(lay.n_tiles)))]
+    with pytest.raises(ValueError, match="colors"):
+        FabricLayout.build(200, n_dies=1, colors=3)
 
 
 def test_layout_color_phases_spread_over_dies():
@@ -159,18 +169,18 @@ def test_fabric_deterministic_per_seed():
 
 
 def test_fabric_same_init_stream_as_block_lns():
-    # identical (seed, restarts) must start both decomposition tiers from
-    # the same initial states — the duel benchmark compares them at equal
-    # footing, so the rng draw order is contract
+    # identical (seed, restarts) must start the one-color (chip-lns) and
+    # two-color (fabric-jax) settings from the same initial states — the
+    # rng draw order is contract, whatever the color count
     rng = np.random.default_rng(3)
     J = rng.integers(-15, 16, size=(100, 100)).astype(np.float64)
     J = np.triu(J, 1) + np.triu(J, 1).T
-    fab = FabricLNS(_engine(), inner_runs=4)
-    blk = BlockLNS(_engine(), inner_runs=4)
-    out_f, _ = fab.solve([J], restarts=4, outer_sweeps=0, seed=5)
-    out_b, _ = blk.solve([J], restarts=4, outer_sweeps=0, seed=5)
-    assert np.array_equal(out_f[0][2], out_b[0][2])   # same init energies
-    assert np.array_equal(out_f[0][1], out_b[0][1])   # same init states
+    two = FabricLNS(_engine(), inner_runs=4)
+    one = FabricLNS(_engine(), mesh=fabric_mesh(1), inner_runs=4, colors=1)
+    out_2, _ = two.solve([J], restarts=4, outer_sweeps=0, seed=5)
+    out_1, _ = one.solve([J], restarts=4, outer_sweeps=0, seed=5)
+    assert np.array_equal(out_2[0][2], out_1[0][2])   # same init energies
+    assert np.array_equal(out_2[0][1], out_1[0][1])   # same init states
 
 
 def test_fabric_multi_problem_batch():
@@ -199,10 +209,13 @@ def test_fabric_multi_problem_batch():
 ])
 # 'fused' runs the Pallas kernel once per die on its slice of the batch
 @pytest.mark.parametrize("path", ["scan", "fused"])
-def test_fabric_bitwise_mesh_invariant(n, k, path):
+@pytest.mark.parametrize("colors", [1, 2])
+def test_fabric_bitwise_mesh_invariant(n, k, path, colors):
     k = len(jax.devices()) if k is None else k
-    _, _, out_1, _ = _solve_fabric(n=n, mesh=fabric_mesh(1), path=path)
-    _, lns, out_k, _ = _solve_fabric(n=n, mesh=fabric_mesh(k), path=path)
+    _, _, out_1, _ = _solve_fabric(n=n, mesh=fabric_mesh(1), path=path,
+                                   colors=colors)
+    _, lns, out_k, _ = _solve_fabric(n=n, mesh=fabric_mesh(k), path=path,
+                                     colors=colors)
     assert lns.ledger["batch_devices"] == k
     assert np.array_equal(out_1[0][0], out_k[0][0])
     assert np.array_equal(out_1[0][1], out_k[0][1])
@@ -230,8 +243,22 @@ def test_fabric_registry_ledger_and_meta():
     assert fab["color_peaks"] and fab["restarts"] == 2
 
 
+def test_chip_lns_is_one_color_on_one_die():
+    p = gset_problem(130, seed=SEED, degree=5.0)
+    rep = get_solver("chip-lns", anneal_sweeps=0.5, inner_runs=4,
+                     outer_sweeps=2).solve(p, runs=2, seed=SEED)
+    fab = rep.meta["fabric"]
+    assert fab["n_colors"] == 1 and fab["mesh_devices"] == 1
+    assert rep.dispatches == fab["dispatches"] == 2
+    # the two settings take exactly their own options
+    with pytest.raises(TypeError):
+        get_solver("chip-lns", mesh_devices=2)
+    with pytest.raises(TypeError):
+        get_solver("fabric-jax", colors=1)
+
+
 # ---------------------------------------------------------------------------
-# BlockLNS hoist regression (satellite: precompute out of the sweep loop)
+# one-color hoist regression (precompute out of the sweep loop)
 # ---------------------------------------------------------------------------
 
 def test_block_lns_dispatch_count_and_no_per_sweep_restack(monkeypatch):
@@ -247,15 +274,16 @@ def test_block_lns_dispatch_count_and_no_per_sweep_restack(monkeypatch):
     rng = np.random.default_rng(SEED)
     J = rng.integers(-15, 16, size=(100, 100)).astype(np.float64)
     J = np.triu(J, 1) + np.triu(J, 1).T
-    lns = BlockLNS(_engine(), inner_runs=4)
+    lns = FabricLNS(_engine(), mesh=fabric_mesh(1), inner_runs=4, colors=1)
     _, d = lns.solve([J], restarts=2, outer_sweeps=5, seed=SEED)
     assert d == 5                         # one dispatch per outer sweep
     # the batch template is hoisted: no per-sweep re-stack/re-pad at all
     assert calls["pad_stack"] == 0
-    t = lns.last_timings
-    assert t["dispatches"] == 5
-    assert t["t_engine"] > 0 and t["t_host"] >= 0
-    assert t["t_total"] >= t["t_engine"]
+    assert lns.ledger["dispatches"] == 5
+    assert len(lns.ledger["per_sweep"]) == 5
+    for rec in lns.ledger["per_sweep"]:
+        assert rec["t_engine"] > 0
+        assert rec["t_total"] >= rec["t_engine"]
 
 
 # ---------------------------------------------------------------------------
